@@ -169,6 +169,9 @@ class TrainSession(Session):
             "first_loss": losses[0] if losses else None,
             "last_loss": losses[-1] if losses else None,
             "losses": losses,
+            # wall seconds per step, device work included (the loss read
+            # syncs); the first step of a run also pays its compile
+            "step_s": [e.duration for e in watchdog.events],
             "slow_steps": sum(1 for e in watchdog.events if e.slow),
             "state": state,
             "mesh": spec.shape.mesh.label(),
@@ -555,7 +558,6 @@ class DryrunSession(Session):
             memory_summary,
             roofline_terms,
         )
-        from repro.runtime.compat import cost_analysis_dict
 
         spec, r = self.spec, self.resolved
         arch = self._arch_for_lower()
@@ -594,7 +596,7 @@ class DryrunSession(Session):
         t_compile = time.time() - t0
 
         bf16c = (mode == "dense")  # TPU-native bf16; CPU legalized to f32
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         mem = memory_summary(compiled.memory_analysis())
         hlo_text = compiled.as_text()
         coll = collective_bytes(hlo_text, bf16_correct=bf16c)
@@ -611,7 +613,7 @@ class DryrunSession(Session):
                                serve_dtype)
             shadow_c = shadow.compile()
             t_cost_compile = time.time() - t0
-            cost = cost_analysis_dict(shadow_c)
+            cost = shadow_c.cost_analysis()
             shadow_text = shadow_c.as_text()
             coll = collective_bytes(shadow_text, bf16_correct=bf16c)
             adj = fusion_adjusted_bytes(

@@ -123,9 +123,10 @@ def load_checkpoint(
 
         treedef = pickle.loads(bytes.fromhex(manifest["treedef"]))
     else:
-        from repro.runtime.compat import deserialize_treedef
+        from jaxlib._jax import pytree
 
-        treedef = deserialize_treedef(bytes.fromhex(manifest["treedef"]))
+        treedef = pytree.PyTreeDef.deserialize_using_proto(
+            jax.tree_util.default_registry, bytes.fromhex(manifest["treedef"]))
     return step, jax.tree_util.tree_unflatten(treedef, leaves)
 
 

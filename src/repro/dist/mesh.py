@@ -9,7 +9,14 @@ and the device pool must be large enough to honor them.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def auto_mesh(shape: tuple, axes: tuple) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the logical sharding
+    constraints (``runtime/sharding.py``) may only name Auto axes, and
+    ``make_mesh`` defaults to Explicit ones."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_explicit_mesh(pod: int, data: int, model: int) -> Mesh:
@@ -26,7 +33,7 @@ def make_explicit_mesh(pod: int, data: int, model: int) -> Mesh:
             f"devices but only {have} are visible; on a CPU host export "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             "before jax initializes")
-    return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
+    return auto_mesh((pod, data, model), ("pod", "data", "model"))
 
 
 def data_axis_size(mesh: Mesh) -> int:

@@ -20,13 +20,14 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import packed_all_gather
 from repro.dist.mesh import data_axis_size
-from repro.runtime.compat import shard_map
 from repro.runtime.sharding import logical_to_spec, sharding_context
 from repro.runtime.tree_sharding import logical_axes_for_path
 from repro.serving.steps import make_decode_step, make_prefill_step
 
 #: shard_map rules for serving: only the data axis participates (pod and
-#: model axes stay replicated here); unknown logical axes replicate.
+#: model axes stay replicated here); unknown logical axes replicate.  The
+#: shard_maps are manual over every axis, since a Pallas (Mosaic) kernel
+#: in the body cannot be partitioned over an axis left to the compiler.
 DATA_ONLY_RULES: dict[str, tuple] = {
     "batch": (("data",),),
     "cache_batch": (("data",),),
@@ -70,11 +71,11 @@ def make_sharded_prefill_step(arch, step_cfg, mesh, reduced: bool = False,
 
     def prefill(params, batch, key):
         _, cache_shape = jax.eval_shape(base, params, batch, key)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), _row_specs(batch, mesh), P()),
             out_specs=(P(), _cache_specs(cache_shape, mesh)),
-            axis_names={"data"}, check_vma=False,
+            check_vma=False,
         )
         return fn(params, batch, key)
 
@@ -92,12 +93,12 @@ def make_sharded_decode_step(arch, step_cfg, mesh, reduced: bool = False,
 
     def decode(params, tokens, cache, key):
         _, cache_shape = jax.eval_shape(base, params, tokens, cache, key)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), _row_specs(tokens, mesh), _cache_specs(cache, mesh),
                       P()),
             out_specs=(P(), _cache_specs(cache_shape, mesh)),
-            axis_names={"data"}, check_vma=False,
+            check_vma=False,
         )
         return fn(params, tokens, cache, key)
 

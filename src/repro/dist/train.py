@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import packed_all_reduce_mean
 from repro.dist.mesh import data_axis_size
-from repro.runtime.compat import shard_map
 from repro.runtime.train import make_train_step
 
 
@@ -62,9 +61,11 @@ def make_sharded_train_step(arch, step_cfg, mesh, reduced: bool = False,
             jax.tree_util.tree_map(lambda _: P(), state),
             P(),
         )
-        fn = shard_map(
+        # manual over every mesh axis: a Pallas (Mosaic) kernel in the
+        # body cannot be partitioned over an axis left to the compiler
+        fn = jax.shard_map(
             base, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names={"data"}, check_vma=False,
+            check_vma=False,
         )
         return fn(state, batch)
 

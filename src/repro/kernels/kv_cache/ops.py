@@ -20,9 +20,8 @@ Implementation ladder:
              mode (lane-padded, trimmed to the canonical word count);
   pallas     the same kernel compiled on TPU.
 
-``kv_unpack`` is a shift-and-test + gather on every backend; its
-interpret/pallas registrations alias the vectorized lowering (the
-mask_unpack precedent) and are excluded from the parity sweep.
+``kv_unpack`` is a shift-and-test + gather on every backend and registers
+only its ``ref`` and ``jnp`` lowerings.
 """
 
 from __future__ import annotations
@@ -167,10 +166,6 @@ registry.register_op("kv_unpack", oracle="ref", examples=_unpack_examples,
                      compare={"kind": "exact"})
 registry.register_impl("kv_unpack", "ref", priority=10)(_unpack_ref)
 registry.register_impl("kv_unpack", "jnp", priority=20)(_unpack_jnp)
-registry.register_impl("kv_unpack", "interpret", selectable=False,
-                       parity=False)(_unpack_jnp)
-registry.register_impl("kv_unpack", "pallas", priority=30, parity=False,
-                       available=registry.on_tpu)(_unpack_jnp)
 
 
 # -- public wrappers ----------------------------------------------------------

@@ -3,8 +3,8 @@
 Two kernels:
 
   * ``mask_pack``: dense f32 block -> packed uint32 mask words (1 bit per
-    element, 32 per word — the Fig. 5 storage format).  Realized as a
-    shift-and-reduce over 32-lane groups on the VPU.
+    element, 32 per word — the Fig. 5 storage format).  Realized as two
+    exact 0/1 x power-of-two matmuls on the MXU, one per half word.
   * ``dangling_filter``: the pre-compute sparsity module's mask generation
     + dangling-data filter (Figs. 7a/7b) on dense-layout operand tiles:
     joint = (a != 0) & (w != 0); each operand keeps only joint survivors.
@@ -26,11 +26,32 @@ COLS = 1024  # lanes; must be a multiple of 32
 WORDS = COLS // 32
 
 
+def _half_word_weights(half: int) -> jax.Array:
+    """(COLS, WORDS) f32: lane c feeds word c // 32 with weight
+    2**(c % 32 - 16 * half) when bit c % 32 lies in 16-bit ``half``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (COLS, WORDS), 0)
+    word = jax.lax.broadcasted_iota(jnp.int32, (COLS, WORDS), 1)
+    bit = lane & 31
+    hit = ((lane >> 5) == word) & ((bit >> 4) == half)
+    weight = jnp.left_shift(jnp.int32(1), bit & 15).astype(jnp.float32)
+    return jnp.where(hit, weight, 0.0)
+
+
 def _pack_kernel(x_ref, out_ref):
-    bits = (x_ref[...] != 0.0).astype(jnp.uint32)  # (ROWS, COLS)
-    b = bits.reshape(ROWS, WORDS, 32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (ROWS, WORDS, 32), 2)
-    out_ref[...] = (b << shifts).sum(axis=2).astype(jnp.uint32)
+    # The shift-and-OR over each 32-lane group runs as two matmuls on the
+    # MXU, one per 16-bit half word: the bits are 0/1 and the weights are
+    # powers of two below 2**16, so every product and f32 partial sum is
+    # exact.  (Mosaic has no unsigned reductions and no lane-splitting
+    # reshape, which the plain shift-and-sum would need.)
+    bits = (x_ref[...] != 0.0).astype(jnp.float32)  # (ROWS, COLS)
+    lo, hi = (
+        jnp.dot(bits, _half_word_weights(half),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+        for half in (0, 1)
+    )
+    words = lo | (hi << 16)
+    out_ref[...] = jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def mask_pack_pallas(x: jax.Array, *, interpret: bool = False) -> jax.Array:

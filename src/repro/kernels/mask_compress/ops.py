@@ -3,10 +3,8 @@
 Three registered ops: ``mask_pack`` (values -> packed occupancy words),
 ``mask_unpack`` (its inverse) and ``dangling_filter`` (zero each operand
 where the other is zero — SPRING's pre-compute filter).  ``mask_unpack``
-is a shift-and-test on the VPU lanes on every backend, so its
-``interpret``/``pallas`` registrations alias the same vectorized lowering
-(kept so whole-program policy pins resolve uniformly); the aliases are
-excluded from the parity suite.
+is a shift-and-test on the VPU lanes on every backend and registers only
+its ``ref`` lowering.
 """
 
 from __future__ import annotations
@@ -93,10 +91,6 @@ registry.register_impl("mask_pack", "pallas", priority=30,
 
 registry.register_op("mask_unpack", oracle="ref")
 registry.register_impl("mask_unpack", "ref", priority=10)(_unpack_ref)
-registry.register_impl("mask_unpack", "interpret", selectable=False,
-                       parity=False)(_unpack_ref)
-registry.register_impl("mask_unpack", "pallas", priority=30, parity=False,
-                       available=registry.on_tpu)(_unpack_ref)
 
 registry.register_op("dangling_filter", oracle="ref",
                      examples=_dangling_examples, compare={"kind": "exact"})

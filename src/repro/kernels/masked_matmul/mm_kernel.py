@@ -5,7 +5,8 @@ module + MAC lanes (paper Figs. 6-8, DESIGN.md §2/P1):
 
   * Operands are Q(IL,FL) grid values.  Per-(128x128)-tile *occupancy
     masks* (the AND-reduction of SPRING's element binary masks over a
-    tile) are computed outside and streamed in as scalars.
+    tile) are computed outside and prefetched into SMEM as flat scalar
+    tables, one entry per tile.
   * The grid walks (M/bm, N/bn, K/bk); a k-step issues the MXU matmul
     only when ``x_occ[i,k] AND w_occ[k,j]`` — the AND-mask gate of
     Fig. 7(a) lifted to tile granularity.  All-zero tiles cost no MXU
@@ -27,6 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.prng import hash_uint32, uniform_from_bits
 
@@ -40,14 +42,15 @@ def padded_dims(m: int, n: int, k: int) -> tuple[int, int, int]:
 
 
 def _mm_kernel(
-    x_ref,
-    w_ref,
     xo_ref,
     wo_ref,
     seed_ref,
+    x_ref,
+    w_ref,
     out_ref,
     *,
     k_steps: int,
+    n_tiles: int,
     n_pad: int,
     fl: int,
     min_v: float,
@@ -60,7 +63,7 @@ def _mm_kernel(
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    occupied = (xo_ref[0, 0] & wo_ref[0, 0]) != 0
+    occupied = (xo_ref[i * k_steps + k] & wo_ref[k * n_tiles + j]) != 0
 
     @pl.when(occupied)
     def _mac():
@@ -83,7 +86,7 @@ def _mm_kernel(
             gi = jnp.uint32(i) * jnp.uint32(BM) + rows
             gj = jnp.uint32(j) * jnp.uint32(BN) + cols
             counter = gi * jnp.uint32(n_pad) + gj
-            u = uniform_from_bits(hash_uint32(counter, seed_ref[0, 0]))
+            u = uniform_from_bits(hash_uint32(counter, seed_ref[0]))
             rounded = lo + (u < frac).astype(jnp.float32)
             out_ref[...] = jnp.clip(rounded * jnp.float32(2.0**-fl), min_v, max_v)
 
@@ -102,7 +105,8 @@ def masked_matmul_pallas(
 ) -> jax.Array:
     """(M,K) @ (K,N) with tile skipping. Inputs must be block-padded.
 
-    x_occ: (M/BM, K/BK) int32; w_occ: (K/BK, N/BN) int32.
+    x_occ: (M/BM, K/BK) int32; w_occ: (K/BK, N/BN) int32.  Both tables
+    and the seed are scalar-prefetched into SMEM (flattened row-major).
     """
     m, k = x.shape
     k2, n = w.shape
@@ -112,6 +116,7 @@ def masked_matmul_pallas(
     kernel = functools.partial(
         _mm_kernel,
         k_steps=grid[2],
+        n_tiles=grid[1],
         n_pad=n,
         fl=fl,
         min_v=-(2.0**il),
@@ -120,22 +125,28 @@ def masked_matmul_pallas(
     )
     kwargs = {}
     if not interpret:
-        from jax.experimental.pallas import tpu as pltpu
-
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((BM, BK), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((BK, BN), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((BM, BK), lambda i, j, kk, *_: (i, kk)),
+            pl.BlockSpec((BK, BN), lambda i, j, kk, *_: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((BM, BN), lambda i, j, kk: (i, j)),
+        out_specs=pl.BlockSpec((BM, BN), lambda i, j, kk, *_: (i, j)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, w, x_occ.astype(jnp.int32), w_occ.astype(jnp.int32), seed.astype(jnp.uint32).reshape(1, 1))
+        **kwargs,
+    )(
+        x_occ.astype(jnp.int32).reshape(-1),
+        w_occ.astype(jnp.int32).reshape(-1),
+        seed.astype(jnp.uint32).reshape(1),
+        x,
+        w,
+    )

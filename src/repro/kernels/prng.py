@@ -31,5 +31,10 @@ def hash_uint32(counter: jnp.ndarray, seed: jnp.ndarray) -> jnp.ndarray:
 
 
 def uniform_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
-    """uint32 -> float32 uniform in [0, 1) with 24-bit resolution."""
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    """uint32 -> float32 uniform in [0, 1) with 24-bit resolution.
+
+    The shifted value is below 2**24, so it converts through int32 exactly
+    (Mosaic has no uint32 -> float32 cast).
+    """
+    top24 = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top24.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))

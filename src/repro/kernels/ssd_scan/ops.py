@@ -6,7 +6,8 @@ any backend (the CPU dry-run path) and serves as the production fallback.
 
 Registry entries: ``ref`` (sequential oracle), ``jnp`` (vectorized
 chunked form — the only impl supporting ``return_state=True``, the
-prefill -> decode cache handoff), ``interpret``, ``pallas`` (TPU).
+prefill -> decode cache handoff), ``interpret``, ``pallas`` (TPU).  The
+kernel impls differentiate through the VJP of the chunked jnp form.
 """
 
 from __future__ import annotations
@@ -92,8 +93,7 @@ def _ssd_jnp(x, dt, a, b, c, *, return_state=False):
     return ssd_scan_jnp(x, dt, a, b, c, return_state=return_state)
 
 
-@partial(jax.jit, static_argnames=("return_state", "interpret"))
-def _ssd_kernel(x, dt, a, b, c, *, return_state=False, interpret=False):
+def _ssd_kernel_call(x, dt, a, b, c, interpret):
     s = x.shape[1]
     pad = (-s) % CHUNK
     if pad:
@@ -103,6 +103,23 @@ def _ssd_kernel(x, dt, a, b, c, *, return_state=False, interpret=False):
         c = jnp.pad(c, ((0, 0), (0, pad), (0, 0), (0, 0)))
     y = ssd_scan_pallas(x, dt, a, b, c, interpret=interpret)
     return y[:, :s]
+
+
+# A pallas_call has no reverse-mode rule, so training differentiates the
+# kernel through the VJP of the chunked jnp form (same math, recomputed
+# in the backward pass from the inputs).
+_ssd_kernel_vjp = jax.custom_vjp(_ssd_kernel_call, nondiff_argnums=(5,))
+_ssd_kernel_vjp.defvjp(
+    lambda x, dt, a, b, c, interpret: (
+        _ssd_kernel_call(x, dt, a, b, c, interpret), (x, dt, a, b, c)),
+    lambda interpret, res, g: jax.vjp(ssd_scan_jnp, *res)[1](g),
+)
+
+
+@partial(jax.jit, static_argnames=("return_state", "interpret"))
+def _ssd_kernel(x, dt, a, b, c, *, return_state=False, interpret=False):
+    del return_state  # unsupported: the registry routes stateful calls away
+    return _ssd_kernel_vjp(x, dt, a, b, c, interpret)
 
 
 def _supports_state(return_state: bool = False) -> bool:

@@ -5,7 +5,8 @@ NOT in cost_analysis, so we parse the optimized HLO text and sum the
 result-shape bytes of every all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute (assignment §Roofline).  Hardware
 constants: TPU v5e-class — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+~50 GB/s/link ICI.  The terms are estimates from a compile, not device
+measurements, so no utilization or fraction of peak is derived here.
 """
 
 from __future__ import annotations
@@ -165,8 +166,6 @@ def roofline_terms(
     }
     dom = max(("compute_s", "memory_s", "collective_s"), key=lambda k: terms[k])
     terms["dominant"] = dom
-    bound = max(compute_s, memory_s, collective_s)
-    terms["roofline_fraction_of_peak"] = (compute_s / bound) if bound > 0 else 0.0
     if model_flops is not None:
         terms["model_flops"] = float(model_flops)
         g = flops_pc * n_chips

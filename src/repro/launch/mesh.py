@@ -9,12 +9,14 @@ from __future__ import annotations
 
 import jax
 
+from repro.dist.mesh import auto_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int | None = None, *, multi_pod: bool = False):
@@ -22,5 +24,5 @@ def make_debug_mesh(n_devices: int | None = None, *, multi_pod: bool = False):
     n = n_devices or len(jax.devices())
     if multi_pod:
         assert n % 2 == 0 and n >= 8
-        return jax.make_mesh((2, 2, n // 4), ("pod", "data", "model"))
-    return jax.make_mesh((2, n // 2), ("data", "model"))
+        return auto_mesh((2, 2, n // 4), ("pod", "data", "model"))
+    return auto_mesh((2, n // 2), ("data", "model"))

@@ -51,8 +51,8 @@ def dryrun_table(rows: list[dict]) -> str:
 
 def roofline_table(rows: list[dict], mesh: str = "single") -> str:
     lines = [
-        "| arch | shape | compute s | memory s | collective s | dominant | frac-of-peak | MODEL_FLOPS | useful ratio |",
-        "|---|---|---|---|---|---|---|---|---|",
+        "| arch | shape | compute s | memory s | collective s | dominant | MODEL_FLOPS | useful ratio |",
+        "|---|---|---|---|---|---|---|---|",
     ]
     for r in rows:
         if r["status"] != "ok" or r["mesh"] != mesh:
@@ -61,7 +61,7 @@ def roofline_table(rows: list[dict], mesh: str = "single") -> str:
         lines.append(
             f"| {r['arch']} | {r['shape']} "
             f"| {t['compute_s']:.4f} | {t['memory_s']:.4f} | {t['collective_s']:.4f} "
-            f"| {t['dominant'].replace('_s','')} | {t['roofline_fraction_of_peak']:.3f} "
+            f"| {t['dominant'].replace('_s','')} "
             f"| {t.get('model_flops',0):.3e} | {t.get('useful_flops_ratio',0):.2f} |")
     return "\n".join(lines)
 
@@ -279,10 +279,7 @@ def pick_hillclimb(rows: list[dict]) -> list[str]:
     notes = []
     if not ok:
         return notes
-    worst = min(ok, key=lambda r: r["roofline"]["roofline_fraction_of_peak"])
     coll = max(ok, key=lambda r: r["roofline"]["collective_s"])
-    notes.append(f"worst-fraction: {worst['arch']} x {worst['shape']} "
-                 f"(frac {worst['roofline']['roofline_fraction_of_peak']:.3f})")
     notes.append(f"most-collective-bound: {coll['arch']} x {coll['shape']} "
                  f"(coll {coll['roofline']['collective_s']:.3f}s)")
     return notes

@@ -22,11 +22,13 @@ keep their historical signatures as wrappers for programmatic callers.
 from __future__ import annotations
 
 import json
+import sys
 
 from repro.api.cli import flag, make_parser, run_main
 from repro.api.sessions import ServeSession, serve_spec
 from repro.api.spec import RunSpec, KernelsSection, NumericsSection
 from repro.core.spring_ops import MODES, SpringConfig  # legacy import site
+from repro.runtime.compile_cache import enable_compile_cache
 
 LEGACY_FLAGS = (
     flag("--arch", "arch.id"),
@@ -135,9 +137,10 @@ def build_parser():
     return make_parser(__doc__, LEGACY_FLAGS, json_out=True)
 
 
-def main(argv=None):
+def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     spec = run_main("serve", args, LEGACY_FLAGS, base=CLI_BASE)
+    enable_compile_cache()
     out = ServeSession(spec).run()
     print(f"prefill {out['prefill_s']*1e3:.1f}ms, decode {out['decode_s']*1e3:.1f}ms "
           f"({out['tokens_per_s']:.1f} tok/s), finite={out['finite']}")
@@ -183,7 +186,11 @@ def main(argv=None):
                                       if len(out["generated"]) else [])
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=2, default=float)
+    if not out["finite"]:
+        print("error: non-finite logits", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -21,10 +21,13 @@ from __future__ import annotations
 
 import json
 import logging
+import math
+import sys
 
 from repro.api.cli import flag, make_parser, run_main
 from repro.api.sessions import TrainSession, train_spec
 from repro.core.spring_ops import MODES  # re-export (legacy import site)
+from repro.runtime.compile_cache import enable_compile_cache
 
 log = logging.getLogger("repro.train")
 
@@ -81,10 +84,11 @@ def build_parser():
     return make_parser(__doc__, LEGACY_FLAGS, json_out=True)
 
 
-def main(argv=None):
+def main(argv=None) -> int:
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(message)s")
     args = build_parser().parse_args(argv)
     spec = run_main("train", args, LEGACY_FLAGS)
+    enable_compile_cache()
     out = TrainSession(spec).run()
     print(f"loss {out['first_loss']:.4f} -> {out['last_loss']:.4f} "
           f"({spec.train.steps} steps, slow={out['slow_steps']}) "
@@ -93,7 +97,11 @@ def main(argv=None):
         payload = {k: v for k, v in out.items() if k != "state"}
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=2, default=float)
+    if not all(math.isfinite(v) for v in out["losses"]):
+        print("error: non-finite training loss", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

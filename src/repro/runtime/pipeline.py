@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.runtime.compat import shard_map
-
 
 def pipeline_apply(
     stage_fn: Callable[[jax.Array, any], jax.Array],
@@ -86,7 +84,7 @@ def pipeline_apply(
         gathered = jax.lax.all_gather(acc_out, axis)  # (S, M, mb, ...)
         return gathered[n_stages - 1]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P()),

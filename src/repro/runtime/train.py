@@ -27,7 +27,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.spring_ops import DENSE, KeyGen, SpringConfig
 from repro.memstash.config import MemstashConfig
-from repro.runtime.compat import shard_map
 from repro.models import encdec as ed_mod
 from repro.models import lm as lm_mod
 from repro.models.layers import SpringContext
@@ -234,7 +233,7 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
             jax.tree_util.tree_map(lambda _: P(), state),
             P(),
         )
-        fn = shard_map(
+        fn = jax.shard_map(
             compressed_body, mesh=mesh,
             in_specs=in_specs, out_specs=out_specs,
             axis_names={"pod"}, check_vma=False,

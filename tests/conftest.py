@@ -1,20 +1,9 @@
 """Test config: single CPU device (the dry-run sets its own device count
-in a subprocess), moderate hypothesis budgets for the 1-core container.
-
-The container may not ship ``hypothesis``; in that case a deterministic
-fallback shim (tests/_hypothesis_fallback.py) is installed so the property
-tests still run instead of aborting collection."""
+in a subprocess), moderate hypothesis budgets."""
 
 import jax
 import pytest
-
-try:
-    from hypothesis import HealthCheck, settings
-except ModuleNotFoundError:
-    from _hypothesis_fallback import install
-
-    install()
-    from hypothesis import HealthCheck, settings
+from hypothesis import HealthCheck, settings
 
 settings.register_profile(
     "ci",

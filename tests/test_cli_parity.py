@@ -198,3 +198,57 @@ def test_examples_flags_are_runspec_backed():
         ispec.loader.exec_module(mod)
         for lf in mod.FLAGS:
             assert lf.path in field_paths(), (name, lf.option)
+
+
+# -- launchers: compile cache and exit status --------------------------------
+
+
+class _FakeSession:
+    result: dict = {}
+
+    def __init__(self, spec, **_):
+        pass
+
+    def run(self):
+        return dict(self.result)
+
+
+@pytest.mark.parametrize("finite", [True, False])
+def test_train_launcher_exits_nonzero_on_nonfinite_loss(monkeypatch, finite):
+    loss = 1.0 if finite else float("nan")
+    fake = type("Fake", (_FakeSession,), {"result": {
+        "losses": [2.0, loss], "first_loss": 2.0, "last_loss": loss,
+        "slow_steps": 0, "spec_hash": "x", "state": None}})
+    monkeypatch.setattr(launch_train, "TrainSession", fake)
+    monkeypatch.setattr(launch_train, "enable_compile_cache", lambda: "")
+    assert launch_train.main(["--set", "train.steps=2"]) == (0 if finite else 1)
+
+
+@pytest.mark.parametrize("finite", [True, False])
+def test_serve_launcher_exits_nonzero_on_nonfinite_logits(monkeypatch, finite):
+    fake = type("Fake", (_FakeSession,), {"result": {
+        "prefill_s": 0.1, "decode_s": 0.1, "tokens_per_s": 1.0,
+        "finite": finite, "engine": False, "generated": [],
+        "spec_hash": "x"}})
+    monkeypatch.setattr(launch_serve, "ServeSession", fake)
+    monkeypatch.setattr(launch_serve, "enable_compile_cache", lambda: "")
+    assert launch_serve.main([]) == (0 if finite else 1)
+
+
+def test_compile_cache_keeps_env_dir_else_uses_checkout(monkeypatch, tmp_path):
+    import jax
+
+    from repro.runtime import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert (compile_cache.CHECKOUT_CACHE.parent / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -67,7 +67,7 @@ def test_every_cpu_impl_roundtrips_bit_exactly():
     want = np.asarray(x)
     for pack_impl in ("ref", "jnp", "interpret"):
         packed = kv_pack(x, impl=pack_impl)
-        for unpack_impl in ("ref", "jnp", "interpret"):
+        for unpack_impl in ("ref", "jnp"):
             dec = kv_unpack(packed["values"], packed["mask"], x.size,
                             impl=unpack_impl)
             np.testing.assert_array_equal(np.asarray(dec), want)

@@ -164,19 +164,17 @@ SERVE_SETS = ["arch.id=llama3.2-1b", "shape.batch=4", "shape.prompt_len=8",
 def test_axis_mode_matches_simulation(debug_mesh):
     """The real wire hop: shard_map'd collectives over the data axis
     reproduce simulation mode bit-for-bit."""
-    from repro.runtime.compat import shard_map
-
     x = C._shard_block(6, 4, 512, 0.4)
     flat = x.reshape(-1)  # P("data") slices back to the stacked rows
 
-    ag = shard_map(lambda v: C.packed_all_gather(v, axis_name="data"),
-                   mesh=debug_mesh, in_specs=P("data"), out_specs=P(),
-                   axis_names={"data"}, check_vma=False)
+    ag = jax.shard_map(lambda v: C.packed_all_gather(v, axis_name="data"),
+                       mesh=debug_mesh, in_specs=P("data"), out_specs=P(),
+                       axis_names={"data"}, check_vma=False)
     assert jnp.array_equal(jax.jit(ag)(flat), C.packed_all_gather(x))
 
-    rs = shard_map(lambda v: C.packed_reduce_scatter(v, axis_name="data"),
-                   mesh=debug_mesh, in_specs=P("data"), out_specs=P("data"),
-                   axis_names={"data"}, check_vma=False)
+    rs = jax.shard_map(lambda v: C.packed_reduce_scatter(v, axis_name="data"),
+                       mesh=debug_mesh, in_specs=P("data"),
+                       out_specs=P("data"), axis_names={"data"}, check_vma=False)
     assert jnp.array_equal(jax.jit(rs)(flat),
                            C.packed_reduce_scatter(x).reshape(-1))
 

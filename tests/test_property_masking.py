@@ -1,5 +1,4 @@
-"""Property-based tests (hypothesis; the deterministic fallback shim fills
-in when the real package is absent) for the binary-mask machinery:
+"""Property-based tests (hypothesis) for the binary-mask machinery:
 ``core/masking.py`` collapse/expand and the ``mask_compress`` pack/unpack
 ops — random shapes and densities, bit-exact roundtrips, and packed wire
 bytes matching the perfmodel traffic formula ``bits/elem = 20*density + 1``

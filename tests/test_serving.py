@@ -99,10 +99,14 @@ def test_eos_truncates_and_is_included(small_model):
     tokens, EOS included; co-tenants are unaffected by its early exit."""
     view, step_cfg, params, prompts = small_model
     base, _ = _run_prompts(view, step_cfg, params, prompts[:2], GEN, n_slots=2)
-    eos = base[0][2]  # the token request 0 greedily emits at step 3
+    # EOS: the first token after step 1 that request 0 has not emitted
+    # before, so the request stops there and not on an earlier repeat
+    cut = next(k for k in range(1, len(base[0]))
+               if base[0][k] not in base[0][:k])
+    eos = base[0][cut]
     got, _ = _run_prompts(view, step_cfg, params, prompts[:2], GEN, n_slots=2,
                           eos=eos)
-    assert got[0] == base[0][:3] and got[0][-1] == eos
+    assert got[0] == base[0][:cut + 1] and got[0][-1] == eos
     # request 1 may legitimately also hit this eos token; only check that
     # what it did emit is the unchanged prefix of its eos-free generation
     assert got[1] == base[1][: len(got[1])]

@@ -5,9 +5,6 @@ the same object the engine drives with real jitted steps).
 Invariants: no slot leaks, FCFS admission order preserved (no
 starvation), every request completes with exactly min(steps-to-eos,
 max_tokens) tokens, total decode ticks >= the longest request.
-
-Runs under real hypothesis when installed, else the deterministic
-fallback shim (tests/_hypothesis_fallback.py).
 """
 
 import pytest

@@ -70,12 +70,10 @@ def test_compressed_allreduce_int8_error_feedback():
     def run(grads):
         return compressed_allreduce_tree(grads, "pod", jax.random.PRNGKey(1))
 
-    from repro.runtime.compat import shard_map
-
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(jax.tree_util.tree_map(lambda _: P(), g),),
-                   out_specs=(jax.tree_util.tree_map(lambda _: P(), g),) * 2,
-                   check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(jax.tree_util.tree_map(lambda _: P(), g),),
+                       out_specs=(jax.tree_util.tree_map(lambda _: P(), g),) * 2,
+                       check_vma=False)
     out, ef = fn(g)
     # mean over 1 shard == dequantized value; residual = original - dequant
     np.testing.assert_allclose(np.asarray(out["w"] + ef["w"]), np.asarray(g["w"]),
