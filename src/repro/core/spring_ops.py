@@ -132,9 +132,10 @@ def _q(x: jax.Array, cfg: SpringConfig, keys: Optional[KeyGen],
     stochastic = cfg.stochastic
     if role in ("act", "weight") and cfg.operand_rounding == "nearest":
         stochastic = False
-    if stochastic and keys is not None:
-        return ste_quantize_stochastic(keys.next(), x, cfg.fmt)
-    return ste_quantize_nearest(x, cfg.fmt)
+    with jax.named_scope("spring_quantize"):
+        if stochastic and keys is not None:
+            return ste_quantize_stochastic(keys.next(), x, cfg.fmt)
+        return ste_quantize_nearest(x, cfg.fmt)
 
 
 def spring_matmul(
@@ -143,12 +144,16 @@ def spring_matmul(
     cfg: SpringConfig = DENSE,
     keys: Optional[KeyGen] = None,
     w_mask: Optional[jax.Array] = None,
+    probe: Optional[jax.Array] = None,
 ) -> jax.Array:
     """``x @ w`` under the configured SPRING numerics.
 
     x: (..., K); w: (K, N); w_mask: optional (K, N) {0,1} pruning mask
     (the weight-sparsity source for LM archs; CNN activation sparsity
-    arises naturally from ReLU and is captured by the value pattern).
+    arises naturally from ReLU and is captured by the value pattern);
+    probe: the step's masked_matmul tile counter
+    (``SpringContext.tile_probe``), used where the sparsity-aware
+    backward is in force.
     """
     if cfg.mode == "dense":
         if w_mask is not None:
@@ -177,7 +182,8 @@ def spring_matmul(
             # (the outer _q is then an on-grid identity); without the
             # custom_vjp backward this path is forward-only (Pallas calls
             # define no autodiff rule)
-            y = mm_ops.masked_matmul(xq, wq, impl=kimpl.name, backward=bwd)
+            y = mm_ops.masked_matmul(xq, wq, impl=kimpl.name, backward=bwd,
+                                     probe=probe)
         elif bwd != "none":
             # "ref"/auto-CPU with sparse backward: the forward is the ref
             # impl with the SR epilogue disabled — bit-identical to the
@@ -185,7 +191,7 @@ def spring_matmul(
             # while dL/dX / dL/dW resolve through masked_matmul_dx/dw.
             # The STE epilogue still comes from the outer _q.
             y = mm_ops.masked_matmul(xq, wq, impl="ref", apply_sr=False,
-                                     backward=bwd)
+                                     backward=bwd, probe=probe)
         else:
             # "ref"/auto-CPU: the differentiable jnp lowering — fp32
             # accumulate on the fixed-point grid (DESIGN.md deviation 2)
@@ -225,16 +231,19 @@ def _conv_nhwc(x, w, stride, padding):
         dimension_numbers=_CONV_DNUMS)
 
 
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _conv_with_sparse_bwd(x, w, stride, padding, bwd_impl):
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _conv_with_sparse_bwd(x, w, probe, stride, padding, bwd_impl):
+    del probe
     return _conv_nhwc(x, w, stride, padding)
 
 
-def _conv_sb_fwd(x, w, stride, padding, bwd_impl):
+def _conv_sb_fwd(x, w, probe, stride, padding, bwd_impl):
+    del probe
     return _conv_nhwc(x, w, stride, padding), (x, w)
 
 
 def _conv_sb_bwd(stride, padding, bwd_impl, res, g):
+    from repro.kernels.masked_matmul import ops as mm_ops
     from repro.kernels.masked_matmul.backward import (
         masked_matmul_dw, masked_matmul_dx)
 
@@ -249,8 +258,8 @@ def _conv_sb_bwd(stride, padding, bwd_impl, res, g):
     # conv_general_dilated_patches orders the patch features (Cin, R, S).
     p = _lax.conv_general_dilated_patches(
         x, filter_shape=(r, s), window_strides=stride, padding=padding,
-        dimension_numbers=_CONV_DNUMS)
-    dw = masked_matmul_dw(p.reshape(-1, cin * r * s), g2, impl=impl)
+        dimension_numbers=_CONV_DNUMS).reshape(-1, cin * r * s)
+    dw = masked_matmul_dw(p, g2, impl=impl)
     dw = dw.reshape(cin, r, s, cout).transpose(1, 2, 0, 3)
 
     # dX: transpose-conv as dilated cotangent patches x flipped weights.
@@ -263,9 +272,15 @@ def _conv_sb_bwd(stride, padding, bwd_impl, res, g):
     pg = _lax.conv_general_dilated_patches(
         g, filter_shape=(r, s), window_strides=(1, 1), padding=bwd_pads,
         lhs_dilation=stride, dimension_numbers=_CONV_DNUMS)
+    pg = pg.reshape(-1, cout * r * s)
     wt = w[::-1, ::-1].transpose(3, 0, 1, 2).reshape(cout * r * s, cin)
-    dx = masked_matmul_dx(pg.reshape(-1, cout * r * s), wt.T, impl=impl)
-    return dx.reshape(n, h, wd, cin), dw
+    dx = masked_matmul_dx(pg, wt.T, impl=impl)
+    # the tile counter (see spring_matmul): the forward is a plain conv,
+    # so only the dx and dw calls count
+    counts = jnp.concatenate([jnp.zeros((2,), jnp.float32),
+                              mm_ops.tile_counts(pg, wt),
+                              mm_ops.tile_counts(p.T, g2)])
+    return dx.reshape(n, h, wd, cin), dw, counts
 
 
 _conv_with_sparse_bwd.defvjp(_conv_sb_fwd, _conv_sb_bwd)
@@ -279,8 +294,10 @@ def spring_conv2d(
     stride: tuple[int, int] = (1, 1),
     padding: str = "SAME",
     feature_group_count: int = 1,
+    probe: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """NHWC conv under SPRING numerics. w: (R, S, Cin/g, Cout)."""
+    """NHWC conv under SPRING numerics. w: (R, S, Cin/g, Cout).  ``probe``
+    counts the backward's masked_matmul tiles, as in ``spring_matmul``."""
     if cfg.mode == "dense":
         return jax.lax.conv_general_dilated(
             x.astype(cfg.dense_dtype),
@@ -298,8 +315,12 @@ def spring_conv2d(
         # (dX/dW) route through masked_matmul_dx/dw.  Grouped/depthwise
         # convs keep dense autodiff — their patch matrices interleave
         # groups and defeat the tiled kernels.
+        if probe is None:
+            from repro.kernels.masked_matmul.backward import PROBE_SIZE
+
+            probe = jnp.zeros((PROBE_SIZE,), jnp.float32)
         y = _conv_with_sparse_bwd(
-            xq.astype(jnp.float32), wq.astype(jnp.float32),
+            xq.astype(jnp.float32), wq.astype(jnp.float32), probe,
             tuple(stride), padding, cfg.backward_sparsity)
         return _q(y, cfg, keys)
     y = jax.lax.conv_general_dilated(

@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.kernels import registry
 from repro.kernels.masked_matmul import ops as mm_ops
-from repro.kernels.masked_matmul.mm_kernel import BK, BM, BN, padded_dims
+from repro.kernels.masked_matmul.mm_kernel import BK, BM, BN
 
 __all__ = [
     "masked_matmul_dx",
@@ -50,6 +50,7 @@ __all__ = [
     "mm_call_with_backward",
     "backward_tile_skip",
     "sparsity_probe",
+    "PROBE_SIZE",
 ]
 
 
@@ -68,18 +69,14 @@ def _blocked_dot(a: jax.Array, b: jax.Array) -> jax.Array:
     SPRING's tile-AND gate.  Tiles whose joint occupancy is empty are
     multiplied by a 0.0 gate, contributing exactly +0.0 to the fp32
     accumulator — same numerics contract as the Pallas kernel's skip."""
-    m, k = a.shape
-    _, n = b.shape
-    m_pad, n_pad, k_pad = padded_dims(m, n, k)
-    ap = jnp.pad(a.astype(jnp.float32), ((0, m_pad - m), (0, k_pad - k)))
-    bp = jnp.pad(b.astype(jnp.float32), ((0, k_pad - k), (0, n_pad - n)))
-    at = ap.reshape(m_pad // BM, BM, k_pad // BK, BK).transpose(0, 2, 1, 3)
-    bt = bp.reshape(k_pad // BK, BK, n_pad // BN, BN).transpose(0, 2, 1, 3)
-    a_occ = jnp.any(at != 0.0, axis=(2, 3))  # (Mi, Kk)
-    b_occ = jnp.any(bt != 0.0, axis=(2, 3))  # (Kk, Nj)
-    gate = (a_occ[:, :, None] & b_occ[None, :, :]).astype(jnp.float32)
+    ap, bp, a_occ, b_occ = mm_ops.prepare(a, b)  # (Mi, Kk), (Kk, Nj)
+    (m_pad, k_pad), n_pad = ap.shape, bp.shape[1]
+    with jax.named_scope("spring_mm_prep"):
+        at = ap.reshape(m_pad // BM, BM, k_pad // BK, BK).transpose(0, 2, 1, 3)
+        bt = bp.reshape(k_pad // BK, BK, n_pad // BN, BN).transpose(0, 2, 1, 3)
+        gate = (a_occ[:, :, None] & b_occ[None, :, :]).astype(jnp.float32)
     out = jnp.einsum("ikab,kjbc,ikj->ijac", at, bt, gate)
-    return out.transpose(0, 2, 1, 3).reshape(m_pad, n_pad)[:m, :n]
+    return out.transpose(0, 2, 1, 3).reshape(m_pad, n_pad)[:a.shape[0], :b.shape[1]]
 
 
 def _kernel_dot(a: jax.Array, b: jax.Array, *, interpret: bool) -> jax.Array:
@@ -241,28 +238,40 @@ def _float0_zero(seed: jax.Array):
     return np.zeros(np.shape(seed), dtype=jax.dtypes.float0)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _mm_bw(x, w, seed, il, fl, apply_sr, fwd_impl, bwd_impl):
+#: Length of the tile probe: the ``[issued, total]`` grid steps of the
+#: forward, dx and dw calls, in that order (see ``mm_call_with_backward``).
+PROBE_SIZE = 6
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _mm_bw(x, w, seed, probe, il, fl, apply_sr, fwd_impl, bwd_impl):
+    del probe
     return registry.impls("masked_matmul")[fwd_impl].fn(
         x, w, seed, il=il, fl=fl, apply_sr=apply_sr)
 
 
-def _mm_bw_fwd(x, w, seed, il, fl, apply_sr, fwd_impl, bwd_impl):
+def _mm_bw_fwd(x, w, seed, probe, il, fl, apply_sr, fwd_impl, bwd_impl):
+    del probe
     y = registry.impls("masked_matmul")[fwd_impl].fn(
         x, w, seed, il=il, fl=fl, apply_sr=apply_sr)
-    # Residual: the (sparse) operands only — never the dense accumulator.
-    # The SR epilogue is straight-through in the backward (DESIGN.md §8):
-    # range clipping is handled by the caller's STE quantizer, keeping the
-    # residual at exactly what SPRING's stash stores.
-    return y, (x, w, seed)
+    # Residual: the (sparse) operands only — never the dense accumulator —
+    # plus the forward's two tile counts.  The SR epilogue is
+    # straight-through in the backward (DESIGN.md §8): range clipping is
+    # handled by the caller's STE quantizer, keeping the residual at
+    # exactly what SPRING's stash stores.
+    return y, (x, w, seed, mm_ops.tile_counts(x, w))
 
 
 def _mm_bw_bwd(il, fl, apply_sr, fwd_impl, bwd_impl, res, g):
-    x, w, seed = res
+    x, w, seed, fwd_counts = res
     impl = None if bwd_impl == "auto" else bwd_impl
     dx = masked_matmul_dx(g, w, il=il, fl=fl, impl=impl)
     dw = masked_matmul_dw(x, g, il=il, fl=fl, impl=impl)
-    return dx, dw, _float0_zero(seed)
+    # the probe's cotangent carries this call's tile counts out of the
+    # backward pass; autodiff sums it over every call of the step
+    counts = jnp.concatenate([fwd_counts, mm_ops.tile_counts(g, w.T),
+                              mm_ops.tile_counts(x.T, g)])
+    return dx, dw, _float0_zero(seed), counts
 
 
 _mm_bw.defvjp(_mm_bw_fwd, _mm_bw_bwd)
@@ -324,9 +333,17 @@ def mm_call_with_backward(
     apply_sr: bool,
     fwd_impl: str,
     bwd_impl: str,
+    probe: jax.Array | None = None,
 ) -> jax.Array:
     """Forward through ``fwd_impl`` with dx/dw routed through the
     sparsity-aware backward ops (``bwd_impl``: "auto" or a concrete name).
+
+    ``probe`` is the tile counter: a float32 vector of ``PROBE_SIZE`` that
+    the result does not depend on; its gradient is this call's
+    ``[fwd_issued, fwd_total, dx_issued, dx_total, dw_issued, dw_total]``
+    (see ``ops.tile_counts``).  Differentiating a whole program with
+    respect to one probe shared by every call sums them, under ``scan``,
+    remat and ``jit`` alike.
 
     A concrete ``bwd_impl`` is validated eagerly so a bad pin fails at the
     call site, not inside the backward trace.
@@ -334,4 +351,6 @@ def mm_call_with_backward(
     if bwd_impl != "auto":
         registry.resolve("masked_matmul_dx", bwd_impl, _count=False)
         registry.resolve("masked_matmul_dw", bwd_impl, _count=False)
-    return _mm_bw(x, w, seed, il, fl, apply_sr, fwd_impl, bwd_impl)
+    if probe is None:
+        probe = jnp.zeros((PROBE_SIZE,), jnp.float32)
+    return _mm_bw(x, w, seed, probe, il, fl, apply_sr, fwd_impl, bwd_impl)

@@ -28,6 +28,33 @@ def _occupancy(a: jax.Array, tm: int, tn: int) -> jax.Array:
     return jnp.any(t != 0.0, axis=(1, 3)).astype(jnp.int32)
 
 
+def prepare(x: jax.Array, w: jax.Array):
+    """The pre-compute sparsity stage of ``x @ w``: both operands padded to
+    MXU tiles in float32, and their occupancy tables ``x_occ`` (Mi, Kk)
+    and ``w_occ`` (Kk, Nj), int32 (1 where a tile holds a non-zero)."""
+    with jax.named_scope("spring_mm_prep"):
+        m, k = x.shape
+        _, n = w.shape
+        m_pad, n_pad, k_pad = padded_dims(m, n, k)
+        xp = jnp.pad(x.astype(jnp.float32), ((0, m_pad - m), (0, k_pad - k)))
+        wp = jnp.pad(w.astype(jnp.float32), ((0, k_pad - k), (0, n_pad - n)))
+        return xp, wp, _occupancy(xp, BM, BK), _occupancy(wp, BK, BN)
+
+
+def tile_counts(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``[issued, total]`` MXU grid steps of ``x @ w`` as float32: a step
+    (i, j, k) is issued when ``x_occ[i, k] AND w_occ[k, j]``; padding
+    tiles count.  Built from :func:`prepare`, so inside a jitted program
+    the tables are the ones the kernel's wrapper builds (XLA merges the
+    two identical computations)."""
+    _, _, x_occ, w_occ = prepare(x, w)
+    with jax.named_scope("spring_mm_prep"):
+        issued = jnp.einsum("ik,kj->", x_occ.astype(jnp.float32),
+                            w_occ.astype(jnp.float32))
+        total = x_occ.shape[0] * w_occ.shape[0] * w_occ.shape[1]
+        return jnp.stack([issued, jnp.float32(total)])
+
+
 @partial(jax.jit, static_argnames=("il", "fl", "apply_sr"))
 def _mm_ref(x, w, seed, *, il=4, fl=16, apply_sr=True):
     return masked_matmul_reference(x, w, seed, il=il, fl=fl, apply_sr=apply_sr)
@@ -35,18 +62,12 @@ def _mm_ref(x, w, seed, *, il=4, fl=16, apply_sr=True):
 
 @partial(jax.jit, static_argnames=("il", "fl", "apply_sr", "interpret"))
 def _mm_kernel(x, w, seed, *, il=4, fl=16, apply_sr=True, interpret=False):
-    m, k = x.shape
-    _, n = w.shape
-    m_pad, n_pad, k_pad = padded_dims(m, n, k)
-    xp = jnp.pad(x.astype(jnp.float32), ((0, m_pad - m), (0, k_pad - k)))
-    wp = jnp.pad(w.astype(jnp.float32), ((0, k_pad - k), (0, n_pad - n)))
-    x_occ = _occupancy(xp, BM, BK)
-    w_occ = _occupancy(wp, BK, BN)
+    xp, wp, x_occ, w_occ = prepare(x, w)
     out = masked_matmul_pallas(
         xp, wp, x_occ, w_occ, seed,
         il=il, fl=fl, apply_sr=apply_sr, interpret=interpret,
     )
-    return out[:m, :n]
+    return out[:x.shape[0], :w.shape[1]]
 
 
 def _example_operands(seed: int, shape, sparsity: float = 0.5, fl: int = 8):
@@ -91,6 +112,7 @@ def masked_matmul(
     apply_sr: bool = True,
     impl: str | None = None,
     backward: str | None = None,
+    probe: jax.Array | None = None,
 ) -> jax.Array:
     """Sparsity-aware ``x @ w`` on the Q(il,fl) grid with SR epilogue.
 
@@ -103,7 +125,8 @@ def masked_matmul(
     Pallas paths are not differentiable), while "auto" or a concrete impl
     name wraps the call in a ``custom_vjp`` whose dL/dx / dL/dw are the
     registry-resolved ``masked_matmul_dx`` / ``masked_matmul_dw`` kernels —
-    tile skipping applies in both directions (DESIGN.md §8).
+    tile skipping applies in both directions (DESIGN.md §8).  ``probe``
+    is that path's tile counter (``backward.mm_call_with_backward``).
     """
     if seed is None:
         seed = jnp.uint32(0)
@@ -117,7 +140,8 @@ def masked_matmul(
     from repro.kernels.masked_matmul.backward import mm_call_with_backward
 
     return mm_call_with_backward(x, w, seed, il=il, fl=fl, apply_sr=apply_sr,
-                                 fwd_impl=kimpl.name, bwd_impl=backward)
+                                 fwd_impl=kimpl.name, bwd_impl=backward,
+                                 probe=probe)
 
 
 def tile_skip_fraction(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -126,13 +150,5 @@ def tile_skip_fraction(x: jax.Array, w: jax.Array) -> jax.Array:
     The roofline compute-term scales by (1 - skip_fraction) on TPU; this
     is the analytically-reportable speedup of the kernel (§Perf).
     """
-    m, k = x.shape
-    _, n = w.shape
-    m_pad, n_pad, k_pad = padded_dims(m, n, k)
-    xp = jnp.pad(x.astype(jnp.float32), ((0, m_pad - m), (0, k_pad - k)))
-    wp = jnp.pad(w.astype(jnp.float32), ((0, k_pad - k), (0, n_pad - n)))
-    x_occ = _occupancy(xp, BM, BK).astype(jnp.float32)  # (Mi, Kk)
-    w_occ = _occupancy(wp, BK, BN).astype(jnp.float32)  # (Kk, Nj)
-    issued = jnp.einsum("ik,kj->", x_occ, w_occ)
-    total = x_occ.shape[0] * w_occ.shape[0] * w_occ.shape[1]
+    issued, total = tile_counts(x, w)
     return 1.0 - issued / total

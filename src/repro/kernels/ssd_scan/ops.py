@@ -108,11 +108,16 @@ def _ssd_kernel_call(x, dt, a, b, c, interpret):
 # A pallas_call has no reverse-mode rule, so training differentiates the
 # kernel through the VJP of the chunked jnp form (same math, recomputed
 # in the backward pass from the inputs).
+def _ssd_kernel_bwd(interpret, res, g):
+    with jax.named_scope("spring_ssd_scan_vjp"):
+        return jax.vjp(ssd_scan_jnp, *res)[1](g)
+
+
 _ssd_kernel_vjp = jax.custom_vjp(_ssd_kernel_call, nondiff_argnums=(5,))
 _ssd_kernel_vjp.defvjp(
     lambda x, dt, a, b, c, interpret: (
         _ssd_kernel_call(x, dt, a, b, c, interpret), (x, dt, a, b, c)),
-    lambda interpret, res, g: jax.vjp(ssd_scan_jnp, *res)[1](g),
+    _ssd_kernel_bwd,
 )
 
 

@@ -32,7 +32,6 @@ from functools import partial
 
 import jax
 
-from repro import telemetry
 from repro.memstash.config import MemstashConfig, STASH_POLICIES
 from repro.memstash.format import compress, decompress
 from repro.memstash.instrument import maybe_record
@@ -45,17 +44,16 @@ def _stashed_call(f, scfg: MemstashConfig, name: str, x, aux):
 
 def _stashed_fwd(f, scfg: MemstashConfig, name: str, x, aux):
     y = f(x, aux)
-    # NB: under jit these spans time *tracing* of the pack (staging it
-    # into the program), eager calls time the pack itself — either way
-    # they mark where each stash point's compression enters the step
-    with telemetry.span("memstash.pack", layer=name, elems=int(x.size)):
+    # named scopes, not host spans: under jit the pack and unpack are
+    # device work, which the profiler's trace attributes by these names
+    with jax.named_scope("memstash_pack"):
         sv = compress(x, capacity=scfg.capacity)
     return y, (sv, aux)
 
 
 def _stashed_bwd(f, scfg: MemstashConfig, name: str, res, g):
     sv, aux = res
-    with telemetry.span("memstash.unpack", layer=name, elems=int(sv.n)):
+    with jax.named_scope("memstash_unpack"):
         x = decompress(sv)
     _, vjp = jax.vjp(f, x, aux)
     return vjp(g)

@@ -101,9 +101,10 @@ def conv(
     b = store.get(name + "/b", (cout,), 0.0)
 
     def body(x_, wb):
-        w_, b_ = wb
+        w_, b_, probe = wb
         y_ = spring_conv2d(x_, w_, ctx.cfg, ctx.keys, stride=(stride, stride),
-                           padding=padding, feature_group_count=groups)
+                           padding=padding, feature_group_count=groups,
+                           probe=probe)
         y_ = y_ + b_.astype(y_.dtype)
         if relu:
             y_ = jax.nn.relu(y_)  # the paper's activation-sparsity source
@@ -112,7 +113,7 @@ def conv(
     # The conv input is the previous layer's post-ReLU map — the sparse
     # tensor the backward dW GEMM re-reads, i.e. SPRING's stash target.
     y = checkpoint_apply(body, ctx.stash_policy(name, int(x.size)), ctx.memstash,
-                         name, x, (w, b))
+                         name, x, (w, b, ctx.tile_probe))
     _record(LayerRecord(
         "conv", name,
         macs=int(y.shape[1] * y.shape[2] * cout * (kh * kw * cin // groups)),
@@ -130,13 +131,13 @@ def fc(store: ParamStore, ctx: SpringContext, name: str, x: jax.Array, cout: int
     b = store.get(name + "/b", (cout,), 0.0)
 
     def body(x_, wb):
-        w_, b_ = wb
-        y_ = spring_matmul(x_, w_, ctx.cfg, ctx.keys)
+        w_, b_, probe = wb
+        y_ = spring_matmul(x_, w_, ctx.cfg, ctx.keys, probe=probe)
         y_ = y_ + b_.astype(y_.dtype)
         return jax.nn.relu(y_) if relu else y_
 
     y = checkpoint_apply(body, ctx.stash_policy(name, int(x.size)), ctx.memstash,
-                         name, x, (w, b))
+                         name, x, (w, b, ctx.tile_probe))
     _record(LayerRecord("fc", name, macs=cin * cout, in_elems=cin,
                         w_elems=cin * cout, out_elems=cout))
     return y

@@ -33,6 +33,11 @@ class SpringContext:
     # Compressed-activation-stash policy for training (memstash subsystem);
     # None means every stash point resolves to "none".
     memstash: Optional[MemstashConfig] = None
+    # masked_matmul tile counter of a training step: a zero float32 vector
+    # the loss is differentiated against; its gradient sums every call's
+    # issued/total grid steps (kernels/masked_matmul/backward.py).  Code
+    # that hands the context to a custom_vjp passes it as an input.
+    tile_probe: Optional[jax.Array] = None
 
     def stash_policy(self, name: str, elems: Optional[int] = None) -> str:
         """Resolve the checkpoint policy for one named stash point."""
@@ -110,7 +115,8 @@ def dense_apply(
     w = constrain(params["kernel"], w_logical)
     w = ctx.maybe_prune(w)
     shape = x.shape
-    y = spring_matmul(x.reshape(-1, shape[-1]), w, ctx.cfg, ctx.keys)
+    y = spring_matmul(x.reshape(-1, shape[-1]), w, ctx.cfg, ctx.keys,
+                      probe=ctx.tile_probe)
     y = y.reshape(*shape[:-1], w.shape[-1])
     if "bias" in params:
         y = (y + params["bias"].astype(y.dtype)).astype(y.dtype)

@@ -273,8 +273,8 @@ def lm_hidden(
             # the 20-vs-32-bit value width; see DESIGN.md §4.3).
             # Active regardless of cfg.remat — the stash *is* the
             # checkpointing strategy (compressed-input remat).  Every
-            # traced value the unit needs (positions, SR key) must flow
-            # through aux, not the closure: custom_vjp backward re-traces
+            # traced value the unit needs (positions, SR key, tile probe)
+            # must flow through aux, not the closure: custom_vjp backward re-traces
             # inside the scan transpose, where closure-captured tracers
             # from the forward trace would leak as jaxpr consts.
             # draw a fresh subkey for the scanned units: reusing the base
@@ -286,9 +286,10 @@ def lm_hidden(
                 h, aux_c = carry
 
                 def unit(h_, aux):
-                    aux_cc, up, pos, k = aux
-                    ctx_u = (dataclasses.replace(ctx, keys=KeyGen(k))
-                             if k is not None else ctx)
+                    aux_cc, up, pos, k, probe = aux
+                    ctx_u = dataclasses.replace(
+                        ctx, keys=KeyGen(k) if k is not None else ctx.keys,
+                        tile_probe=probe)
                     for u, kind in enumerate(cfg.pattern_unit):
                         h_, _, a = block_apply(up[u], h_, ctx_u, cfg, kind, pos)
                         h_ = checkpoint_name(h_, "block_out")
@@ -296,7 +297,8 @@ def lm_hidden(
                     return h_, aux_cc
 
                 return stash_apply(unit, scfg, "lm/residual", h,
-                                   (aux_c, unit_params, positions, base_key)), None
+                                   (aux_c, unit_params, positions, base_key,
+                                    ctx.tile_probe)), None
         elif cfg.remat or stash_policy == "remat":
             body_fn = jax.checkpoint(body)
         else:

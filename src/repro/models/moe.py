@@ -67,10 +67,10 @@ def _expert_ffn(buf: jax.Array, params, ctx: SpringContext) -> jax.Array:
 
     def one(args):
         b, wg, wu, wd = args
-        g = spring_matmul(b, wg, ctx.cfg, ctx.keys)
-        u = spring_matmul(b, wu, ctx.cfg, ctx.keys)
+        g = spring_matmul(b, wg, ctx.cfg, ctx.keys, probe=ctx.tile_probe)
+        u = spring_matmul(b, wu, ctx.cfg, ctx.keys, probe=ctx.tile_probe)
         h = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
-        return spring_matmul(h, wd, ctx.cfg, ctx.keys)
+        return spring_matmul(h, wd, ctx.cfg, ctx.keys, probe=ctx.tile_probe)
 
     return jax.lax.map(one, (buf, w_gate, w_up, w_down))
 

@@ -61,8 +61,9 @@ def _finalize_weights(new_p, cfg: OptimizerConfig, key: Optional[jax.Array]):
         return new_p
     assert key is not None, "fixed-point weight update needs an rng key"
     leaves, treedef = jax.tree_util.tree_flatten(new_p)
-    keys = jax.random.split(key, len(leaves))
-    out = [quantize_stochastic(k, p, cfg.weight_format) for k, p in zip(keys, leaves)]
+    with jax.named_scope("spring_quantize"):
+        keys = jax.random.split(key, len(leaves))
+        out = [quantize_stochastic(k, p, cfg.weight_format) for k, p in zip(keys, leaves)]
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

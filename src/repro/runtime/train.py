@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.spring_ops import DENSE, KeyGen, SpringConfig
+from repro.kernels.masked_matmul.backward import PROBE_SIZE
 from repro.memstash.config import MemstashConfig
 from repro.models import encdec as ed_mod
 from repro.models import lm as lm_mod
@@ -146,61 +147,84 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
     packed reduce-scatter/all-gather gradient exchange (DESIGN.md §14).
     It composes with the ``compress_pod_grads`` int8+EF pod link, which
     stays where it was (per-pod grads differ; the data-axis exchange
-    ``grad_sync`` carries is a different link)."""
+    ``grad_sync`` carries is a different link).
+
+    The step's metrics hold ``mm_tiles`` where the sparsity-aware
+    backward is in force: masked_matmul's ``[fwd_issued, fwd_total,
+    dx_issued, dx_total, dw_issued, dw_total]`` grid steps of the step,
+    float32 (exact below 2**24).  The optimizer runs under
+    ``jax.named_scope("spring_optimizer")``."""
     cfg = arch.reduced() if reduced else arch.config
     _, opt_update = make_optimizer(step_cfg.optimizer)
     spring_cfg = _spring_for(step_cfg)
 
-    def ctx_for(key) -> SpringContext:
+    # masked_matmul's tile counter: where the sparsity-aware backward is in
+    # force, the loss is differentiated against a zero probe as well, whose
+    # gradient sums every call's [fwd, dx, dw] x [issued, total] grid steps
+    count_tiles = spring_cfg.sparse_backward
+
+    def ctx_for(key, probe=None) -> SpringContext:
         keys = KeyGen(key) if spring_cfg.is_quantized else None
         return SpringContext(cfg=spring_cfg, keys=keys,
                              prune_ratio=step_cfg.prune_ratio,
-                             memstash=step_cfg.memstash)
+                             memstash=step_cfg.memstash, tile_probe=probe)
+
+    def value_and_grads(params, batch, key):
+        """((loss, metrics), grads, tiles); tiles is None where nothing is
+        counted."""
+        def loss_fn(p, probe):
+            return _loss_for(arch, cfg, p, batch, ctx_for(key, probe))
+
+        if not count_tiles:
+            out, grads = jax.value_and_grad(loss_fn, has_aux=True)(params, None)
+            return out, grads, None
+        out, (grads, tiles) = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(
+            params, jnp.zeros((PROBE_SIZE,), jnp.float32))
+        return out, grads, tiles
 
     def grads_and_loss(params, batch, key):
-        def loss_fn(p):
-            loss, metrics = _loss_for(arch, cfg, p, batch, ctx_for(key))
-            return loss, metrics
-
         if step_cfg.microbatch is None:
-            (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-            return loss, metrics, grads
+            (loss, metrics), grads, tiles = value_and_grads(params, batch, key)
+            return loss, metrics, grads, tiles
         # gradient accumulation over microbatches (memory-bound shapes)
         nm = step_cfg.microbatch
 
-        def one(i):
+        def body(carry, i):
+            acc_loss, acc_grads, acc_tiles, p = carry
             mb = jax.tree_util.tree_map(
                 lambda x: x.reshape(nm, x.shape[0] // nm, *x.shape[1:])[i], batch
             )
-            def lf(p):
-                loss, metrics = _loss_for(arch, cfg, p, mb, ctx_for(jax.random.fold_in(key, i)))
-                return loss, metrics
-            return jax.value_and_grad(lf, has_aux=True)(p)
-
-        def body(carry, i):
-            acc_loss, acc_grads, p = carry
-            (loss, metrics), grads = one(i)
+            (loss, metrics), grads, tiles = value_and_grads(p, mb, jax.random.fold_in(key, i))
+            if tiles is not None:
+                acc_tiles = acc_tiles + tiles
             return (acc_loss + loss / nm,
                     jax.tree_util.tree_map(lambda a, g: a + g / nm, acc_grads, grads),
-                    p), metrics
+                    acc_tiles, p), metrics
 
-        p = params
         zero_g = jax.tree_util.tree_map(lambda x: jnp.zeros_like(x, jnp.float32), params)
-        (loss, grads, _), metrics = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), zero_g, p), jnp.arange(nm)
+        (loss, grads, tiles, _), metrics = jax.lax.scan(
+            body, (jnp.zeros((), jnp.float32), zero_g,
+                   jnp.zeros((PROBE_SIZE,), jnp.float32), params), jnp.arange(nm)
         )
         metrics = jax.tree_util.tree_map(lambda m: m[-1], metrics)
-        return loss, metrics, grads
+        return loss, metrics, grads, tiles if count_tiles else None
+
+    def step_metrics(metrics, loss, om, tiles) -> dict:
+        metrics = dict(metrics, loss=loss, **om)
+        if tiles is not None:
+            metrics["mm_tiles"] = tiles
+        return metrics
 
     def plain_step(state: TrainState, batch) -> tuple[TrainState, dict]:
         key = jax.random.fold_in(state.rng, state.step)
         with sharding_context(mesh, _rules_for(step_cfg)):
-            loss, metrics, grads = grads_and_loss(state.params, batch, key)
+            loss, metrics, grads, tiles = grads_and_loss(state.params, batch, key)
             if grad_sync is not None:
                 grads = grad_sync(grads)
-            new_p, new_opt, om = opt_update(grads, state.opt_state, state.params,
-                                            jax.random.fold_in(key, 0x5eed))
-        metrics = dict(metrics, loss=loss, **om)
+            with jax.named_scope("spring_optimizer"):
+                new_p, new_opt, om = opt_update(grads, state.opt_state, state.params,
+                                                jax.random.fold_in(key, 0x5eed))
+        metrics = step_metrics(metrics, loss, om, tiles)
         return TrainState(new_p, new_opt, state.step + 1, state.rng, state.ef), metrics
 
     if not step_cfg.compress_pod_grads:
@@ -212,15 +236,16 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
     def compressed_body(state: TrainState, batch):
         key = jax.random.fold_in(state.rng, state.step)
         with sharding_context(mesh, _rules_for(step_cfg)):
-            loss, metrics, grads = grads_and_loss(state.params, batch, key)
+            loss, metrics, grads, tiles = grads_and_loss(state.params, batch, key)
             # int8 + error feedback across pods (per-pod grads differ since
             # each pod saw different data)
             grads, new_ef = compressed_allreduce_tree(
                 grads, "pod", jax.random.fold_in(key, 0xc0de), state.ef
             )
-            new_p, new_opt, om = opt_update(grads, state.opt_state, state.params,
-                                            jax.random.fold_in(key, 0x5eed))
-        metrics = dict(metrics, loss=loss, **om)
+            with jax.named_scope("spring_optimizer"):
+                new_p, new_opt, om = opt_update(grads, state.opt_state, state.params,
+                                                jax.random.fold_in(key, 0x5eed))
+        metrics = step_metrics(metrics, loss, om, tiles)
         return TrainState(new_p, new_opt, state.step + 1, state.rng, new_ef), metrics
 
     def compressed_step(state: TrainState, batch):

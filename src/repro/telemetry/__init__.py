@@ -35,7 +35,7 @@ from repro.telemetry.spans import SpanTracer, validate_chrome_trace
 __all__ = [
     "TelemetryConfig", "MetricsRegistry", "QuantileSketch", "SpanTracer",
     "default_registry", "validate_chrome_trace",
-    "span", "instant", "enabled", "tracer", "scope", "metrics",
+    "span", "enabled", "tracer", "scope", "metrics",
 ]
 
 
@@ -85,19 +85,13 @@ def span(name: str, **args):
 
     Disabled path = one attribute load + one None test + returning a
     shared no-op context manager (the overhead gate budget in
-    ``benchmarks/bench_serving.py`` measures exactly this call).
+    ``benchmarks/bench_serving.py`` measures exactly this call).  Enabled,
+    the span also lands in a running JAX profiler trace under its name.
     """
     t = _AMBIENT.tracer
     if t is None:
         return _NULL
     return t.span(name, **args)
-
-
-def instant(name: str, **args) -> None:
-    """Zero-duration trace marker (no-op when disabled)."""
-    t = _AMBIENT.tracer
-    if t is not None:
-        t.instant(name, **args)
 
 
 def metrics() -> MetricsRegistry:

@@ -7,14 +7,19 @@ pack/unpack.  Each completed span becomes one Chrome ``"ph": "X"``
 (complete) event — ``chrome://tracing`` and https://ui.perfetto.dev load
 the exported file directly.
 
+Each span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+that while a JAX profiler trace is running the same phases land in the
+profiler's trace, on the clock of the device ops.
+
 Overhead contract (DESIGN.md §11): when tracing is disabled — the
 default — ``span()`` is one attribute load, one truthiness test, and the
 return of a shared no-op context manager.  No object allocation, no
 timestamp read, no lock.  The enabled path takes two ``monotonic_ns``
-reads and one list append per span (plus one lock-guarded sampling
-accumulator update per root span); there is deliberately no jax work
-and no device sync inside the tracer, so enabling it cannot perturb
-numerics (the on/off parity seal in tests/test_telemetry.py).
+reads, one profiler annotation and one list append per span (plus one
+lock-guarded sampling accumulator update per root span); there is
+deliberately no jax computation and no device sync inside the tracer, so
+enabling it cannot perturb numerics (the on/off parity seal in
+tests/test_telemetry.py).
 
 Sampling is deterministic (no PRNG — workflows replay): a fractional
 accumulator records ``ceil(k * rate)`` of the first ``k`` top-level
@@ -30,6 +35,8 @@ import threading
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["SpanTracer", "Span", "validate_chrome_trace"]
 
 #: Required keys of a Chrome complete event (the schema CI validates).
@@ -39,7 +46,7 @@ CHROME_EVENT_KEYS = ("name", "ph", "ts", "dur", "pid", "tid")
 class Span:
     """One open span; append-only record closed by ``__exit__``."""
 
-    __slots__ = ("tracer", "name", "args", "_t0", "recorded")
+    __slots__ = ("tracer", "name", "args", "_t0", "recorded", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: dict,
                  recorded: bool):
@@ -48,14 +55,17 @@ class Span:
         self.args = args
         self.recorded = recorded
         self._t0 = 0
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self) -> "Span":
         self.tracer._depth.value += 1
+        self._annotation.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.monotonic_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
         self.tracer._depth.value -= 1
         if self.recorded:
             self.tracer._record(self.name, self._t0, t1, self.args)
@@ -116,20 +126,6 @@ class SpanTracer:
         # dropped root's children look like fresh roots and re-roll the
         # sampling decision mid-tree)
         return Span(self, name, args, recorded=self._depth.root_sampled)
-
-    def instant(self, name: str, **args) -> None:
-        """Zero-duration marker (Chrome ``"ph": "i"`` instant event)."""
-        if not self.enabled or not self._depth.root_sampled:
-            return
-        ev = {
-            "name": name, "ph": "i", "s": "t",
-            "ts": (time.monotonic_ns() - self._epoch_ns) / 1e3,
-            "pid": self._pid, "tid": threading.get_ident(),
-        }
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self._events.append(ev)
 
     def _record(self, name: str, t0_ns: int, t1_ns: int, args: dict) -> None:
         ev = {
@@ -196,16 +192,14 @@ def validate_chrome_trace(data) -> list[dict]:
         if not isinstance(ev, dict):
             raise ValueError(f"event {i} is not an object")
         ph = ev.get("ph")
-        if ph not in ("X", "i"):
+        if ph != "X":
             raise ValueError(f"event {i}: unexpected phase {ph!r}")
-        keys = CHROME_EVENT_KEYS if ph == "X" else tuple(
-            k for k in CHROME_EVENT_KEYS if k != "dur")
-        for k in keys:
+        for k in CHROME_EVENT_KEYS:
             if k not in ev:
                 raise ValueError(f"event {i} ({ev.get('name')!r}): "
                                  f"missing key {k!r}")
         if not isinstance(ev["name"], str) or not ev["name"]:
             raise ValueError(f"event {i}: name must be a non-empty string")
-        if ph == "X" and ev["dur"] < 0:
+        if ev["dur"] < 0:
             raise ValueError(f"event {i}: negative duration")
     return events

@@ -1,0 +1,100 @@
+"""The train step's own measurement, inside the jitted program: the tile
+counter ``metrics["mm_tiles"]`` against tile counts taken eagerly from
+the operands of every masked_matmul call, under the layer scan and both
+recomputing policies; and the named device phases in the step's
+optimized HLO."""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.kernels.masked_matmul import backward as mm_bwd
+
+SCOPES = ("spring_quantize", "spring_mm_prep", "spring_ssd_scan_vjp", "spring_optimizer")
+
+
+def _step(mode="quant_sparse", remat_policy="full", sets=()):
+    """A jitted train step of the reduced mamba2 (4 scanned layers, remat
+    on), its state with in_proj's columns 128:256 zeroed (one empty
+    weight tile per forward and dx call) and one batch."""
+    from repro.api.spec import build_spec
+    from repro.models.lm import lm_init
+    from repro.optim.optimizers import make_optimizer
+    from repro.runtime.train import TrainState, make_train_step
+
+    r = build_spec("train", use_env=False, sets=[
+        "arch.id=mamba2-780m", "arch.reduced=true", "shape.batch=2",
+        "shape.seq=64", f"numerics.mode={mode}", "sparsity.backward=auto",
+        *sets]).resolve()
+    cfg = dataclasses.replace(r.config, remat=True, remat_policy=remat_policy)
+    assert cfg.n_units == 4
+    params = lm_init(jax.random.PRNGKey(0), cfg)
+    mixer = params["unit_0"]["mixer"]
+    mixer["in_proj"]["kernel"] = mixer["in_proj"]["kernel"].at[:, :, 128:256].set(0.0)
+    opt_init, _ = make_optimizer(r.step.optimizer)
+    state = TrainState(params, opt_init(params), jnp.zeros((), jnp.int32),
+                       jax.random.PRNGKey(1), None)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (2, 64), 0, cfg.vocab)}
+    return jax.jit(make_train_step(r.arch.view(config=cfg), r.step)), state, batch
+
+
+def _counts(a, b) -> list:
+    """[issued, total] 128x128 grid steps of ``a @ b``, padding included,
+    in numpy."""
+    a, b = np.asarray(a), np.asarray(b)
+    (m, k), n = a.shape, b.shape[1]
+    mi, ki, ni = -(-m // 128), -(-k // 128), -(-n // 128)
+    ap = np.zeros((mi * 128, ki * 128))
+    ap[:m, :k] = a
+    bp = np.zeros((ki * 128, ni * 128))
+    bp[:k, :n] = b
+    a_occ = (ap.reshape(mi, 128, ki, 128) != 0).any(axis=(1, 3))
+    b_occ = (bp.reshape(ki, 128, ni, 128) != 0).any(axis=(1, 3))
+    return [int((a_occ[:, :, None] & b_occ[None]).sum()), mi * ki * ni]
+
+
+@pytest.mark.parametrize("remat_policy", ["full", "stash"])
+def test_tile_counter_equals_eager_counts(monkeypatch, remat_policy):
+    """Each call's forward (x @ w), dx (g @ w.T) and dw (x.T @ g) counts,
+    taken from its operands by a host callback in the backward rule,
+    sum to the step's in-jit counter exactly: the layers recomputed by
+    ``jax.checkpoint`` or restored from the memstash compressed stash."""
+    seen = []
+    real = mm_bwd._mm_bw.bwd
+
+    def bwd(il, fl, apply_sr, fwd_impl, bwd_impl, res, g):
+        x, w = res[0], res[1]
+        jax.debug.callback(lambda x, w, g: seen.append(
+            _counts(x, w) + _counts(g, w.T) + _counts(x.T, g)), x, w, g)
+        return real(il, fl, apply_sr, fwd_impl, bwd_impl, res, g)
+
+    monkeypatch.setattr(mm_bwd._mm_bw, "bwd", bwd)
+    sets = ["memstash.policy=stash"] if remat_policy == "stash" else []
+    step, state, batch = _step(remat_policy=remat_policy, sets=sets)
+    _, metrics = step(state, batch)
+    tiles = np.asarray(metrics["mm_tiles"])
+    jax.effects_barrier()
+    assert len(seen) == 4 * 2  # in_proj and out_proj of 4 scanned layers
+    want = np.sum(seen, axis=0)
+    assert tiles.dtype == np.float32 and tiles.tolist() == want.tolist()
+    assert want[0] < want[1] and want[2] < want[3]  # the zeroed weight tile
+
+
+def test_dense_step_counts_nothing():
+    step, state, batch = _step(mode="dense")
+    _, metrics = step(state, batch)
+    assert "mm_tiles" not in metrics
+
+
+def test_step_names_its_device_phases():
+    """Each phase scope reaches the compiled step's instruction metadata
+    (ssd_scan pinned to its kernel so that its backward is the VJP)."""
+    step, state, batch = _step(sets=["kernels.policy=ssd_scan=interpret"])
+    hlo = step.lower(state, batch).compile().as_text()
+    for scope in SCOPES:
+        assert f"/{scope}/" in hlo, scope

@@ -228,11 +228,10 @@ def test_tracer_records_and_exports_valid_trace(tmp_path):
     with tr.span("serve.tick", tick=0):
         with tr.span("serve.tick.decode", active=2):
             pass
-    tr.instant("admit", rid=1)
     path = tr.write(str(tmp_path / "t.json"), extra_metadata={"run": "test"})
     events = validate_chrome_trace(open(path).read())
     names = [e["name"] for e in events]
-    assert set(names) == {"serve.tick", "serve.tick.decode", "admit"}
+    assert set(names) == {"serve.tick", "serve.tick.decode"}
     complete = [e for e in events if e["ph"] == "X"]
     assert all(e["dur"] >= 0 for e in complete)
     # child closed before parent: appears first, nested inside in time
@@ -349,12 +348,18 @@ def test_serve_parity_and_attribution_with_telemetry(tmp_path):
 @pytest.mark.slow
 def test_train_parity_with_telemetry(tmp_path):
     """Train losses bit-identical on vs off; the trace carries the step
-    phase taxonomy plus memstash pack/unpack spans."""
+    phase taxonomy, and the jitted step names the memstash pack/unpack
+    work with named scopes."""
+    import jax
+    import jax.numpy as jnp
+
     from repro.api.sessions import session_for, train_spec
     from repro.api.spec import TelemetrySection
+    from repro.runtime.train import init_train_state, make_train_step
 
     spec = train_spec(steps=2, batch=2, seq=16, stash="stash")
-    out_off = session_for(spec).run()
+    session = session_for(spec)
+    out_off = session.run()
     trace = tmp_path / "train_trace.json"
     spec_on = dataclasses.replace(spec, telemetry=TelemetrySection(
         enabled=True, trace_path=str(trace)))
@@ -362,7 +367,34 @@ def test_train_parity_with_telemetry(tmp_path):
     assert out_off["losses"] == out_on["losses"]
     names = {e["name"] for e in validate_chrome_trace(trace.read_text())}
     assert {"train.step", "train.step.data", "train.step.device",
-            "train.step.host", "memstash.pack", "memstash.unpack"} <= names
+            "train.step.host"} <= names
+    r = session.resolved
+    state = init_train_state(jax.random.PRNGKey(0), r.view, r.step, reduced=True)
+    hlo = jax.jit(make_train_step(r.view, r.step)).lower(
+        state, {"tokens": jnp.zeros((2, 16), jnp.int32)}).as_text(debug_info=True)
+    assert "memstash_pack" in hlo and "memstash_unpack" in hlo
+
+
+def test_spans_land_in_the_profiler_trace(tmp_path):
+    """With a telemetry scope active, a span is also a JAX profiler
+    annotation: it shows up in the profiler's own trace by name."""
+    import glob
+
+    import jax
+
+    from repro import telemetry
+
+    jax.profiler.start_trace(str(tmp_path))
+    with telemetry.scope(telemetry.TelemetryConfig(enabled=True)):
+        with telemetry.span("train.step.device"):
+            jax.block_until_ready(jax.numpy.ones(4) * 2)
+    with telemetry.span("outside.any.scope"):
+        pass
+    jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    names = {ev.name for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert "train.step.device" in names and "outside.any.scope" not in names
 
 
 def test_telemetry_spec_section_roundtrip():
