@@ -277,9 +277,9 @@ def _conv_sb_bwd(stride, padding, bwd_impl, res, g):
     dx = masked_matmul_dx(pg, wt.T, impl=impl)
     # the tile counter (see spring_matmul): the forward is a plain conv,
     # so only the dx and dw calls count
-    counts = jnp.concatenate([jnp.zeros((2,), jnp.float32),
-                              mm_ops.tile_counts(pg, wt),
-                              mm_ops.tile_counts(p.T, g2)])
+    counts = mm_ops.probe_counts(jnp.zeros((4,), jnp.float32),
+                                 mm_ops.call_counts(pg, wt),
+                                 mm_ops.call_counts(p.T, g2))
     return dx.reshape(n, h, wd, cin), dw, counts
 
 

@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.kernels import registry
 from repro.kernels.masked_matmul import ops as mm_ops
-from repro.kernels.masked_matmul.mm_kernel import BK, BM, BN
+from repro.kernels.masked_matmul.mm_kernel import TILE
 
 __all__ = [
     "masked_matmul_dx",
@@ -69,11 +69,11 @@ def _blocked_dot(a: jax.Array, b: jax.Array) -> jax.Array:
     SPRING's tile-AND gate.  Tiles whose joint occupancy is empty are
     multiplied by a 0.0 gate, contributing exactly +0.0 to the fp32
     accumulator — same numerics contract as the Pallas kernel's skip."""
-    ap, bp, a_occ, b_occ = mm_ops.prepare(a, b)  # (Mi, Kk), (Kk, Nj)
+    ap, bp, a_occ, b_occ, _, _ = mm_ops.prepare(a, b)  # (Mi, Kk), (Kk, Nj)
     (m_pad, k_pad), n_pad = ap.shape, bp.shape[1]
     with jax.named_scope("spring_mm_prep"):
-        at = ap.reshape(m_pad // BM, BM, k_pad // BK, BK).transpose(0, 2, 1, 3)
-        bt = bp.reshape(k_pad // BK, BK, n_pad // BN, BN).transpose(0, 2, 1, 3)
+        at = ap.reshape(m_pad // TILE, TILE, k_pad // TILE, TILE).transpose(0, 2, 1, 3)
+        bt = bp.reshape(k_pad // TILE, TILE, n_pad // TILE, TILE).transpose(0, 2, 1, 3)
         gate = (a_occ[:, :, None] & b_occ[None, :, :]).astype(jnp.float32)
     out = jnp.einsum("ikab,kjbc,ikj->ijac", at, bt, gate)
     return out.transpose(0, 2, 1, 3).reshape(m_pad, n_pad)[:a.shape[0], :b.shape[1]]
@@ -148,6 +148,11 @@ def _dx_examples() -> list:
     g = _sparse_mat(0, (256, 256), 0.2).at[:128, :].set(0.0)
     cases.append(((g, _sparse_mat(1, (256, 256), 0.2)), {}))
     cases.append(((jnp.zeros((64, 64)), _sparse_mat(2, (64, 64), 0.5)), {}))
+    # blocks 512 x 512 x 384 on the one-dot path; one 256 x 384 x 512
+    # block with an empty cotangent tile, on the sub-tile path
+    cases.append(((_sparse_mat(6, (512, 384), 0.2), _sparse_mat(7, (512, 384), 0.2)), {}))
+    g = _sparse_mat(8, (256, 512), 0.2).at[128:, :128].set(0.0)
+    cases.append(((g, _sparse_mat(9, (384, 512), 0.2)), {}))
     return cases
 
 
@@ -160,6 +165,11 @@ def _dw_examples() -> list:
     x = _sparse_mat(3, (256, 384), 0.2).at[:, 256:].set(0.0)
     cases.append(((x, _sparse_mat(4, (256, 256), 0.2)), {}))
     cases.append(((_sparse_mat(5, (64, 64), 0.5), jnp.zeros((64, 64))), {}))
+    # blocks 512 x 384 x 384 on the one-dot path; 512-row blocks, one
+    # all-empty beside one on the one-dot path
+    cases.append(((_sparse_mat(6, (384, 512), 0.2), _sparse_mat(7, (384, 768), 0.2)), {}))
+    x = _sparse_mat(8, (256, 1024), 0.2).at[:, :512].set(0.0)
+    cases.append(((x, _sparse_mat(9, (256, 256), 0.2)), {}))
     return cases
 
 
@@ -238,9 +248,10 @@ def _float0_zero(seed: jax.Array):
     return np.zeros(np.shape(seed), dtype=jax.dtypes.float0)
 
 
-#: Length of the tile probe: the ``[issued, total]`` grid steps of the
-#: forward, dx and dw calls, in that order (see ``mm_call_with_backward``).
-PROBE_SIZE = 6
+#: Length of the tile probe: the ``[issued, total]`` 128-tile steps of
+#: the forward, dx and dw calls, in that order, then their ``[one_dot,
+#: total]`` block grid steps summed (see ``ops.probe_counts``).
+PROBE_SIZE = 8
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -255,11 +266,11 @@ def _mm_bw_fwd(x, w, seed, probe, il, fl, apply_sr, fwd_impl, bwd_impl):
     y = registry.impls("masked_matmul")[fwd_impl].fn(
         x, w, seed, il=il, fl=fl, apply_sr=apply_sr)
     # Residual: the (sparse) operands only — never the dense accumulator —
-    # plus the forward's two tile counts.  The SR epilogue is
+    # plus the forward's tile and block counts.  The SR epilogue is
     # straight-through in the backward (DESIGN.md §8): range clipping is
     # handled by the caller's STE quantizer, keeping the residual at
     # exactly what SPRING's stash stores.
-    return y, (x, w, seed, mm_ops.tile_counts(x, w))
+    return y, (x, w, seed, mm_ops.call_counts(x, w))
 
 
 def _mm_bw_bwd(il, fl, apply_sr, fwd_impl, bwd_impl, res, g):
@@ -269,8 +280,8 @@ def _mm_bw_bwd(il, fl, apply_sr, fwd_impl, bwd_impl, res, g):
     dw = masked_matmul_dw(x, g, il=il, fl=fl, impl=impl)
     # the probe's cotangent carries this call's tile counts out of the
     # backward pass; autodiff sums it over every call of the step
-    counts = jnp.concatenate([fwd_counts, mm_ops.tile_counts(g, w.T),
-                              mm_ops.tile_counts(x.T, g)])
+    counts = mm_ops.probe_counts(fwd_counts, mm_ops.call_counts(g, w.T),
+                                 mm_ops.call_counts(x.T, g))
     return dx, dw, _float0_zero(seed), counts
 
 
@@ -293,11 +304,11 @@ def sparsity_probe(density: float = 0.5, size: int = 512,
     def tile_sparse(k, shape):
         v = jax.random.normal(k, shape) * 0.05
         keep = jax.random.uniform(
-            jax.random.fold_in(k, 1), (shape[0] // BM, shape[1] // BN)
+            jax.random.fold_in(k, 1), (shape[0] // TILE, shape[1] // TILE)
         ) < density
         if density < 1.0:  # at least one skippable tile per operand
             keep = keep.at[0, 0].set(False)
-        return v * jnp.repeat(jnp.repeat(keep, BM, 0), BN, 1)
+        return v * jnp.repeat(jnp.repeat(keep, TILE, 0), TILE, 1)
 
     x = tile_sparse(jax.random.fold_in(key, 0), (size, size))
     w = tile_sparse(jax.random.fold_in(key, 1), (size, size))
@@ -340,10 +351,10 @@ def mm_call_with_backward(
 
     ``probe`` is the tile counter: a float32 vector of ``PROBE_SIZE`` that
     the result does not depend on; its gradient is this call's
-    ``[fwd_issued, fwd_total, dx_issued, dx_total, dw_issued, dw_total]``
-    (see ``ops.tile_counts``).  Differentiating a whole program with
-    respect to one probe shared by every call sums them, under ``scan``,
-    remat and ``jit`` alike.
+    ``[fwd_issued, fwd_total, dx_issued, dx_total, dw_issued, dw_total,
+    one_dot_blocks, blocks]`` (see ``ops.probe_counts``).  Differentiating
+    a whole program with respect to one probe shared by every call sums
+    them, under ``scan``, remat and ``jit`` alike.
 
     A concrete ``bwd_impl`` is validated eagerly so a bad pin fails at the
     call site, not inside the backward trace.

@@ -3,17 +3,25 @@
 This is the MXU-granular realization of SPRING's pre-compute sparsity
 module + MAC lanes (paper Figs. 6-8, DESIGN.md §2/P1):
 
-  * Operands are Q(IL,FL) grid values.  Per-(128x128)-tile *occupancy
-    masks* (the AND-reduction of SPRING's element binary masks over a
-    tile) are computed outside and prefetched into SMEM as flat scalar
-    tables, one entry per tile.
-  * The grid walks (M/bm, N/bn, K/bk); a k-step issues the MXU matmul
-    only when ``x_occ[i,k] AND w_occ[k,j]`` — the AND-mask gate of
-    Fig. 7(a) lifted to tile granularity.  All-zero tiles cost no MXU
-    work ("ineffectual computations are completely skipped").
+  * Operands are Q(IL,FL) grid values, padded to 128-multiples.  Per-
+    (128x128)-tile *occupancy masks* (the AND-reduction of SPRING's
+    element binary masks over a tile) are computed outside and
+    prefetched into SMEM as flat scalar tables, one entry per tile,
+    beside per-block *full* tables (1 where every 128-tile of the block
+    is occupied).
+  * The grid walks (M/bm, N/bn, K/bk) blocks of 128-tiles, with
+    ``(bm, bn, bk) = block_dims(...)`` chosen from the padded shape
+    alone.  A block step whose x and w blocks are both full issues one
+    MXU matmul over the whole block; any other block step loops over its
+    128-sub-tiles and issues a sub-tile only when
+    ``x_occ[i,k] AND w_occ[k,j]`` — the AND-mask gate of Fig. 7(a)
+    lifted to tile granularity.  All-zero tiles cost no MXU work
+    ("ineffectual computations are completely skipped"), exactly the
+    tiles a grid of single 128-tiles would skip.
   * The epilogue applies stochastic rounding (paper Eq. 4) back to
     Q(IL,FL) using the same counter-based xorshift stream as
-    ``kernels/stochastic_round``.
+    ``kernels/stochastic_round``; the counter is the element's position
+    in the 128-padded output, whatever the block.
 
 Numerics note: skipping a tile whose joint occupancy is empty adds
 exactly 0.0 to the f32 accumulator, so outputs are bit-identical to the
@@ -32,24 +40,36 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.prng import hash_uint32, uniform_from_bits
 
-BM = 128
-BN = 128
-BK = 128
+#: The skip gate's granularity: one MXU tile.  Operands pad to it.
+TILE = 128
+#: Block sides a grid step may take, largest first: each a multiple of
+#: TILE, the largest keeping the double-buffered f32 blocks near 6 MiB.
+BLOCKS = (512, 384, 256, 128)
 
 
 def padded_dims(m: int, n: int, k: int) -> tuple[int, int, int]:
-    return (pl.cdiv(m, BM) * BM, pl.cdiv(n, BN) * BN, pl.cdiv(k, BK) * BK)
+    return (pl.cdiv(m, TILE) * TILE, pl.cdiv(n, TILE) * TILE, pl.cdiv(k, TILE) * TILE)
+
+
+def block_dims(m_pad: int, n_pad: int, k_pad: int) -> tuple[int, int, int]:
+    """``(bm, bn, bk)`` of the kernel's grid for 128-padded dims: each the
+    largest of :data:`BLOCKS` that divides its dim."""
+    return tuple(next(b for b in BLOCKS if d % b == 0) for d in (m_pad, n_pad, k_pad))
 
 
 def _mm_kernel(
     xo_ref,
     wo_ref,
+    xf_ref,
+    wf_ref,
     seed_ref,
     x_ref,
     w_ref,
     out_ref,
     *,
     k_steps: int,
+    n_blocks: int,
+    k_tiles: int,
     n_tiles: int,
     n_pad: int,
     fl: int,
@@ -58,18 +78,42 @@ def _mm_kernel(
     apply_sr: bool,
 ):
     i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    (bm, bk), bn = x_ref.shape, w_ref.shape[1]
+    rm, rn, rk = bm // TILE, bn // TILE, bk // TILE
 
     @pl.when(k == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    occupied = (xo_ref[i * k_steps + k] & wo_ref[k * n_tiles + j]) != 0
+    full = (xf_ref[i * k_steps + k] & wf_ref[k * n_blocks + j]) != 0
 
-    @pl.when(occupied)
-    def _mac():
+    @pl.when(full)
+    def _block():
         out_ref[...] += jnp.dot(
             x_ref[...], w_ref[...], preferred_element_type=jnp.float32
         )
+
+    if rm * rn * rk > 1:  # a one-tile block is full exactly when occupied
+
+        @pl.when(jnp.logical_not(full))
+        def _tiles():
+            def sub_tile(t, carry):
+                a, b, c = t // (rn * rk), (t // rk) % rn, t % rk
+                ti, tj, tk = i * rm + a, j * rn + b, k * rk + c
+
+                @pl.when((xo_ref[ti * k_tiles + tk] & wo_ref[tk * n_tiles + tj]) != 0)
+                def _mac():
+                    rows = pl.ds(pl.multiple_of(a * TILE, TILE), TILE)
+                    cols = pl.ds(pl.multiple_of(b * TILE, TILE), TILE)
+                    inner = pl.ds(pl.multiple_of(c * TILE, TILE), TILE)
+                    out_ref[rows, cols] += jnp.dot(
+                        x_ref[rows, inner], w_ref[inner, cols],
+                        preferred_element_type=jnp.float32,
+                    )
+
+                return carry
+
+            jax.lax.fori_loop(0, rm * rn * rk, sub_tile, 0)
 
     if apply_sr:
 
@@ -83,8 +127,8 @@ def _mm_kernel(
             frac = scaled - lo
             rows = jax.lax.broadcasted_iota(jnp.uint32, acc.shape, 0)
             cols = jax.lax.broadcasted_iota(jnp.uint32, acc.shape, 1)
-            gi = jnp.uint32(i) * jnp.uint32(BM) + rows
-            gj = jnp.uint32(j) * jnp.uint32(BN) + cols
+            gi = jnp.uint32(i) * jnp.uint32(bm) + rows
+            gj = jnp.uint32(j) * jnp.uint32(bn) + cols
             counter = gi * jnp.uint32(n_pad) + gj
             u = uniform_from_bits(hash_uint32(counter, seed_ref[0]))
             rounded = lo + (u < frac).astype(jnp.float32)
@@ -96,6 +140,8 @@ def masked_matmul_pallas(
     w: jax.Array,
     x_occ: jax.Array,
     w_occ: jax.Array,
+    x_full: jax.Array,
+    w_full: jax.Array,
     seed: jax.Array,
     *,
     il: int = 4,
@@ -103,20 +149,26 @@ def masked_matmul_pallas(
     apply_sr: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
-    """(M,K) @ (K,N) with tile skipping. Inputs must be block-padded.
+    """(M,K) @ (K,N) with tile skipping. Inputs must be TILE-padded.
 
-    x_occ: (M/BM, K/BK) int32; w_occ: (K/BK, N/BN) int32.  Both tables
-    and the seed are scalar-prefetched into SMEM (flattened row-major).
+    x_occ: (M/TILE, K/TILE) and w_occ: (K/TILE, N/TILE) int32 per tile;
+    x_full: (M/bm, K/bk) and w_full: (K/bk, N/bn) int32 per block of
+    :func:`block_dims`.  The four tables and the seed are
+    scalar-prefetched into SMEM (flattened row-major).
     """
     m, k = x.shape
     k2, n = w.shape
-    assert k == k2 and m % BM == 0 and n % BN == 0 and k % BK == 0
-    grid = (m // BM, n // BN, k // BK)
+    assert k == k2 and m % TILE == 0 and n % TILE == 0 and k % TILE == 0
+    bm, bn, bk = block_dims(m, n, k)
+    grid = (m // bm, n // bn, k // bk)
+    assert x_full.shape == (grid[0], grid[2]) and w_full.shape == (grid[2], grid[1])
     eps = 2.0**-fl
     kernel = functools.partial(
         _mm_kernel,
         k_steps=grid[2],
-        n_tiles=grid[1],
+        n_blocks=grid[1],
+        k_tiles=k // TILE,
+        n_tiles=n // TILE,
         n_pad=n,
         fl=fl,
         min_v=-(2.0**il),
@@ -129,13 +181,13 @@ def masked_matmul_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=5,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((BM, BK), lambda i, j, kk, *_: (i, kk)),
-            pl.BlockSpec((BK, BN), lambda i, j, kk, *_: (kk, j)),
+            pl.BlockSpec((bm, bk), lambda i, j, kk, *_: (i, kk)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk, *_: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((BM, BN), lambda i, j, kk, *_: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, *_: (i, j)),
     )
     return pl.pallas_call(
         kernel,
@@ -146,6 +198,8 @@ def masked_matmul_pallas(
     )(
         x_occ.astype(jnp.int32).reshape(-1),
         w_occ.astype(jnp.int32).reshape(-1),
+        x_full.astype(jnp.int32).reshape(-1),
+        w_full.astype(jnp.int32).reshape(-1),
         seed.astype(jnp.uint32).reshape(1),
         x,
         w,

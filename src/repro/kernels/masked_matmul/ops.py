@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import registry
-from repro.kernels.masked_matmul.mm_kernel import BK, BM, BN, masked_matmul_pallas, padded_dims
+from repro.kernels.masked_matmul.mm_kernel import (
+    TILE, block_dims, masked_matmul_pallas, padded_dims)
 from repro.kernels.masked_matmul.ref import masked_matmul_reference
 
 
@@ -28,31 +29,68 @@ def _occupancy(a: jax.Array, tm: int, tn: int) -> jax.Array:
     return jnp.any(t != 0.0, axis=(1, 3)).astype(jnp.int32)
 
 
+def _full(occ: jax.Array, rm: int, rn: int) -> jax.Array:
+    """1 where every tile of an (rm, rn)-tile block of ``occ`` is occupied."""
+    m, n = occ.shape
+    return jnp.all(occ.reshape(m // rm, rm, n // rn, rn) != 0, axis=(1, 3)).astype(jnp.int32)
+
+
 def prepare(x: jax.Array, w: jax.Array):
     """The pre-compute sparsity stage of ``x @ w``: both operands padded to
-    MXU tiles in float32, and their occupancy tables ``x_occ`` (Mi, Kk)
-    and ``w_occ`` (Kk, Nj), int32 (1 where a tile holds a non-zero)."""
+    MXU tiles in float32, their occupancy tables ``x_occ`` (Mi, Kk) and
+    ``w_occ`` (Kk, Nj), int32 (1 where a tile holds a non-zero), and the
+    kernel's block tables ``x_full`` (MI, KK) and ``w_full`` (KK, NJ),
+    int32 (1 where every tile of a :func:`block_dims` block is occupied)."""
     with jax.named_scope("spring_mm_prep"):
         m, k = x.shape
         _, n = w.shape
         m_pad, n_pad, k_pad = padded_dims(m, n, k)
+        bm, bn, bk = block_dims(m_pad, n_pad, k_pad)
         xp = jnp.pad(x.astype(jnp.float32), ((0, m_pad - m), (0, k_pad - k)))
         wp = jnp.pad(w.astype(jnp.float32), ((0, k_pad - k), (0, n_pad - n)))
-        return xp, wp, _occupancy(xp, BM, BK), _occupancy(wp, BK, BN)
+        x_occ, w_occ = _occupancy(xp, TILE, TILE), _occupancy(wp, TILE, TILE)
+        rm, rn, rk = bm // TILE, bn // TILE, bk // TILE
+        return xp, wp, x_occ, w_occ, _full(x_occ, rm, rk), _full(w_occ, rk, rn)
+
+
+def _pair_count(a_table: jax.Array, b_table: jax.Array) -> jax.Array:
+    """``[count, total]`` of the (i, j, k) with ``a[i, k] AND b[k, j]``."""
+    hits = jnp.einsum("ik,kj->", a_table.astype(jnp.float32), b_table.astype(jnp.float32))
+    total = a_table.shape[0] * b_table.shape[0] * b_table.shape[1]
+    return jnp.stack([hits, jnp.float32(total)])
 
 
 def tile_counts(x: jax.Array, w: jax.Array) -> jax.Array:
-    """``[issued, total]`` MXU grid steps of ``x @ w`` as float32: a step
-    (i, j, k) is issued when ``x_occ[i, k] AND w_occ[k, j]``; padding
-    tiles count.  Built from :func:`prepare`, so inside a jitted program
-    the tables are the ones the kernel's wrapper builds (XLA merges the
-    two identical computations)."""
-    _, _, x_occ, w_occ = prepare(x, w)
+    """``[issued, total]`` 128-tile MXU steps of ``x @ w`` as float32: a
+    tile step (i, j, k) is issued when ``x_occ[i, k] AND w_occ[k, j]``;
+    padding tiles count.  Built from :func:`prepare`, so inside a jitted
+    program the tables are the ones the kernel's wrapper builds (XLA
+    merges the two identical computations)."""
+    _, _, x_occ, w_occ, _, _ = prepare(x, w)
     with jax.named_scope("spring_mm_prep"):
-        issued = jnp.einsum("ik,kj->", x_occ.astype(jnp.float32),
-                            w_occ.astype(jnp.float32))
-        total = x_occ.shape[0] * w_occ.shape[0] * w_occ.shape[1]
-        return jnp.stack([issued, jnp.float32(total)])
+        return _pair_count(x_occ, w_occ)
+
+
+def block_counts(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``[one_dot, total]`` block grid steps of the kernel on ``x @ w`` as
+    float32: a block step (I, J, K) takes the one-dot path when
+    ``x_full[I, K] AND w_full[K, J]``, from :func:`prepare`'s tables."""
+    _, _, _, _, x_full, w_full = prepare(x, w)
+    with jax.named_scope("spring_mm_prep"):
+        return _pair_count(x_full, w_full)
+
+
+def call_counts(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``tile_counts`` then ``block_counts`` of one kernel call ``x @ w``."""
+    return jnp.concatenate([tile_counts(x, w), block_counts(x, w)])
+
+
+def probe_counts(fwd: jax.Array, dx: jax.Array, dw: jax.Array) -> jax.Array:
+    """The tile probe's cotangent from the :func:`call_counts` of one
+    ``x @ w``'s forward, dx and dw calls: ``[fwd_issued, fwd_total,
+    dx_issued, dx_total, dw_issued, dw_total, one_dot_blocks, blocks]``,
+    the block counts summed over the three calls."""
+    return jnp.concatenate([fwd[:2], dx[:2], dw[:2], fwd[2:] + dx[2:] + dw[2:]])
 
 
 @partial(jax.jit, static_argnames=("il", "fl", "apply_sr"))
@@ -62,9 +100,8 @@ def _mm_ref(x, w, seed, *, il=4, fl=16, apply_sr=True):
 
 @partial(jax.jit, static_argnames=("il", "fl", "apply_sr", "interpret"))
 def _mm_kernel(x, w, seed, *, il=4, fl=16, apply_sr=True, interpret=False):
-    xp, wp, x_occ, w_occ = prepare(x, w)
     out = masked_matmul_pallas(
-        xp, wp, x_occ, w_occ, seed,
+        *prepare(x, w), seed,
         il=il, fl=fl, apply_sr=apply_sr, interpret=interpret,
     )
     return out[:x.shape[0], :w.shape[1]]
@@ -79,15 +116,25 @@ def _example_operands(seed: int, shape, sparsity: float = 0.5, fl: int = 8):
 
 def _examples() -> list:
     cases = []
-    for m, k, n in [(128, 128, 128), (100, 70, 50), (64, 512, 200)]:
+    # blocks (bm, bn, bk): 128^3 (M pads to 128), 128 x 256 x 512 (N pads
+    # 200 -> 256), 512 x 512 x 384, and 384 x 384 x 512 over two k-steps
+    for m, k, n in [(128, 128, 128), (100, 70, 50), (64, 512, 200),
+                    (512, 384, 512), (384, 1024, 384)]:
         x = _example_operands(m * 7 + k, (m, k))
         w = _example_operands(n * 13 + k, (k, n))
         cases.append(((x, w, jnp.uint32(5)), {}))
-    # block-pruned operands: whole MXU tiles skipped, plus the SR-off path
+    # block-pruned operands: one 256 x 256 x 384 block whose empty tiles
+    # the sub-tile path skips, plus the SR-off path
     x = _example_operands(0, (256, 384), 0.3).at[:128, :256].set(0.0)
     w = _example_operands(1, (384, 256), 0.3).at[256:, 128:].set(0.0)
     cases.append(((x, w, jnp.uint32(3)), {}))
     cases.append(((x, w, jnp.uint32(3)), {"apply_sr": False},
+                  {"kind": "allclose", "atol": 1e-6, "rtol": 0.0}))
+    # 512-row blocks: one all-empty beside one on the one-dot path
+    x = _example_operands(2, (1024, 256), 0.3).at[:512].set(0.0)
+    w = _example_operands(3, (256, 256), 0.3)
+    cases.append(((x, w, jnp.uint32(7)), {}))
+    cases.append(((x, w, jnp.uint32(7)), {"apply_sr": False},
                   {"kind": "allclose", "atol": 1e-6, "rtol": 0.0}))
     return cases
 
@@ -145,7 +192,7 @@ def masked_matmul(
 
 
 def tile_skip_fraction(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Fraction of (i,j,k) MXU grid steps skipped for these operands.
+    """Fraction of (i,j,k) 128-tile MXU steps skipped for these operands.
 
     The roofline compute-term scales by (1 - skip_fraction) on TPU; this
     is the analytically-reportable speedup of the kernel (§Perf).

@@ -151,8 +151,9 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
 
     The step's metrics hold ``mm_tiles`` where the sparsity-aware
     backward is in force: masked_matmul's ``[fwd_issued, fwd_total,
-    dx_issued, dx_total, dw_issued, dw_total]`` grid steps of the step,
-    float32 (exact below 2**24).  The optimizer runs under
+    dx_issued, dx_total, dw_issued, dw_total]`` 128-tile steps and its
+    ``[one_dot, total]`` block grid steps of the step, float32 (exact
+    below 2**24).  The optimizer runs under
     ``jax.named_scope("spring_optimizer")``."""
     cfg = arch.reduced() if reduced else arch.config
     _, opt_update = make_optimizer(step_cfg.optimizer)
@@ -160,7 +161,8 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
 
     # masked_matmul's tile counter: where the sparsity-aware backward is in
     # force, the loss is differentiated against a zero probe as well, whose
-    # gradient sums every call's [fwd, dx, dw] x [issued, total] grid steps
+    # gradient sums every call's [fwd, dx, dw] x [issued, total] tile steps
+    # and its [one_dot, total] block grid steps
     count_tiles = spring_cfg.sparse_backward
 
     def ctx_for(key, probe=None) -> SpringContext:

@@ -9,7 +9,9 @@ it from the kernel registry's per-op example inputs and comparison specs.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.kernels.masked_matmul.mm_kernel import BLOCKS, TILE, block_dims, padded_dims
 from repro.kernels.masked_matmul.ops import masked_matmul, tile_skip_fraction
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.stochastic_round.ops import stochastic_round
@@ -38,6 +40,31 @@ def test_masked_matmul_tile_skip_preserves_results():
     a = masked_matmul(x, w, jnp.uint32(3), impl="interpret")
     b = masked_matmul(x, w, jnp.uint32(3), impl="ref")
     assert bool(jnp.all(a == b))
+
+
+@pytest.mark.parametrize("mkn,blocks", [
+    ((2048, 1536, 6448), (512, 384, 512)),   # mamba2-780m in_proj forward
+    ((2048, 3072, 1536), (512, 512, 512)),   # out_proj forward
+    ((2048, 6448, 1536), (512, 512, 384)),   # in_proj dx: g @ w.T
+    ((1536, 2048, 6448), (512, 384, 512)),   # in_proj dw: x.T @ g
+    ((2048, 2048, 8192), (512, 512, 512)),   # llama3.2-1b MLP up
+    ((64, 70, 200), (128, 256, 128)),        # decode-sized M keeps 128 rows
+])
+def test_masked_matmul_block_rule(mkn, blocks):
+    """The kernel's blocks, from the 128-padded (M, N, K) alone."""
+    m, k, n = mkn
+    assert block_dims(*padded_dims(m, n, k)) == blocks
+
+
+def test_masked_matmul_blocks_divide_and_fit():
+    """Every block divides its padded dim and is a whole number of tiles,
+    and the largest blocks' pipelined buffers (x, w and out in float32,
+    double-buffered) stay under v5e's default scoped VMEM limit of 16 MiB."""
+    for d in range(TILE, 64 * TILE + 1, TILE):
+        for b in block_dims(d, d, d):
+            assert b % TILE == 0 and d % b == 0 and b <= max(BLOCKS)
+    bm = bn = bk = max(BLOCKS)
+    assert 2 * 4 * (bm * bk + bk * bn + bm * bn) <= 6 * 2**20 < 16 * 2**20
 
 
 def test_masked_matmul_grad_path():

@@ -43,9 +43,16 @@ def _step(mode="quant_sparse", remat_policy="full", sets=()):
     return jax.jit(make_train_step(r.arch.view(config=cfg), r.step)), state, batch
 
 
+def _block(tiles: int) -> int:
+    """Tiles per block side: the largest side of 512, 384, 256, 128 that
+    divides the padded dim, in tiles."""
+    return next(r for r in (4, 3, 2, 1) if tiles % r == 0)
+
+
 def _counts(a, b) -> list:
-    """[issued, total] 128x128 grid steps of ``a @ b``, padding included,
-    in numpy."""
+    """[issued, total] 128x128 tile steps of ``a @ b``, padding included,
+    and [one_dot, total] block grid steps (blocks whose tiles are all
+    occupied in both operands), in numpy."""
     a, b = np.asarray(a), np.asarray(b)
     (m, k), n = a.shape, b.shape[1]
     mi, ki, ni = -(-m // 128), -(-k // 128), -(-n // 128)
@@ -55,22 +62,33 @@ def _counts(a, b) -> list:
     bp[:k, :n] = b
     a_occ = (ap.reshape(mi, 128, ki, 128) != 0).any(axis=(1, 3))
     b_occ = (bp.reshape(ki, 128, ni, 128) != 0).any(axis=(1, 3))
-    return [int((a_occ[:, :, None] & b_occ[None]).sum()), mi * ki * ni]
+    rm, rk, rn = _block(mi), _block(ki), _block(ni)
+    a_full = a_occ.reshape(mi // rm, rm, ki // rk, rk).all(axis=(1, 3))
+    b_full = b_occ.reshape(ki // rk, rk, ni // rn, rn).all(axis=(1, 3))
+    return [int((a_occ[:, :, None] & b_occ[None]).sum()), mi * ki * ni,
+            int((a_full[:, :, None] & b_full[None]).sum()), a_full.size * b_full.shape[1]]
+
+
+def _probe(x, w, g) -> list:
+    """The probe's 8 counts of one call: each direction's tile counts,
+    then the block counts summed over the three."""
+    fwd, dx, dw = _counts(x, w), _counts(g, w.T), _counts(x.T, g)
+    return fwd[:2] + dx[:2] + dw[:2] + [fwd[2] + dx[2] + dw[2], fwd[3] + dx[3] + dw[3]]
 
 
 @pytest.mark.parametrize("remat_policy", ["full", "stash"])
 def test_tile_counter_equals_eager_counts(monkeypatch, remat_policy):
-    """Each call's forward (x @ w), dx (g @ w.T) and dw (x.T @ g) counts,
-    taken from its operands by a host callback in the backward rule,
-    sum to the step's in-jit counter exactly: the layers recomputed by
-    ``jax.checkpoint`` or restored from the memstash compressed stash."""
+    """Each call's forward (x @ w), dx (g @ w.T) and dw (x.T @ g) tile
+    and block counts, taken from its operands by a host callback in the
+    backward rule, sum to the step's in-jit counter exactly: the layers
+    recomputed by ``jax.checkpoint`` or restored from the memstash
+    compressed stash."""
     seen = []
     real = mm_bwd._mm_bw.bwd
 
     def bwd(il, fl, apply_sr, fwd_impl, bwd_impl, res, g):
         x, w = res[0], res[1]
-        jax.debug.callback(lambda x, w, g: seen.append(
-            _counts(x, w) + _counts(g, w.T) + _counts(x.T, g)), x, w, g)
+        jax.debug.callback(lambda x, w, g: seen.append(_probe(x, w, g)), x, w, g)
         return real(il, fl, apply_sr, fwd_impl, bwd_impl, res, g)
 
     monkeypatch.setattr(mm_bwd._mm_bw, "bwd", bwd)
@@ -82,7 +100,9 @@ def test_tile_counter_equals_eager_counts(monkeypatch, remat_policy):
     assert len(seen) == 4 * 2  # in_proj and out_proj of 4 scanned layers
     want = np.sum(seen, axis=0)
     assert tiles.dtype == np.float32 and tiles.tolist() == want.tolist()
-    assert want[0] < want[1] and want[2] < want[3]  # the zeroed weight tile
+    assert len(want) == mm_bwd.PROBE_SIZE == 8
+    # the zeroed weight tile: skipped tiles, and blocks off the one-dot path
+    assert want[0] < want[1] and want[2] < want[3] and want[6] < want[7]
 
 
 def test_dense_step_counts_nothing():

@@ -2,7 +2,7 @@
 
 Each test lowers one registered ``pallas`` lowering at the real widths the
 train and serve paths use (``llama3.2-1b`` MLP matmuls, ``mamba2-780m``'s
-SSD scan, one slot of the llama KV pool) and compiles it for one chip of
+in_proj and out_proj matmuls and SSD scan, one slot of the llama KV pool) and compiles it for one chip of
 a ``v5e:2x2`` topology described without the chip.  The TPU compiler
 refuses here what it would refuse on the chip: tilings the (8, 128) rule
 forbids, casts and reductions Mosaic does not lower, VMEM overuse.
@@ -23,6 +23,11 @@ from jax.sharding import SingleDeviceSharding
 
 # llama3.2-1b: d_model 2048, d_ff 8192; a 2048-token prefill chunk.
 M, D, F = 2048, 2048, 8192
+# masked_matmul's (M, K, N): llama's MLP up projection; mamba2-780m's
+# in_proj (d_model 1536 -> 6448, which pads to 6528) and out_proj
+# (d_inner 3072 -> 1536), at batch 1 x 2048.
+MATMULS = {"llama_up": (M, D, F), "mamba_in_proj": (2048, 1536, 6448),
+           "mamba_out_proj": (2048, 3072, 1536)}
 # mamba2-780m: 48 heads of 64, one state group of 128; batch 2 x 1024.
 SSD_B, SSD_S, SSD_H, SSD_P, SSD_G, SSD_N = 2, 1024, 48, 64, 1, 128
 # the llama K (or V) pool leaf the engine packs every decode tick:
@@ -77,24 +82,30 @@ def _pallas_fn(op):
     return registry.impls(op)["pallas"].fn
 
 
-def test_masked_matmul_forward_compiles(one_chip, no_persistent_cache):
+@pytest.mark.parametrize("mkn", MATMULS.values(), ids=MATMULS.keys())
+def test_masked_matmul_forward_compiles(one_chip, no_persistent_cache, mkn):
+    m, k, n = mkn
     fn = _pallas_fn("masked_matmul")
     _compile(lambda x, w, s: fn(x, w, s),
-             _spec((M, D), jnp.float32, one_chip),
-             _spec((D, F), jnp.float32, one_chip),
+             _spec((m, k), jnp.float32, one_chip),
+             _spec((k, n), jnp.float32, one_chip),
              _spec((), jnp.uint32, one_chip))
 
 
-def test_masked_matmul_dx_compiles(one_chip, no_persistent_cache):
+@pytest.mark.parametrize("mkn", MATMULS.values(), ids=MATMULS.keys())
+def test_masked_matmul_dx_compiles(one_chip, no_persistent_cache, mkn):
+    m, k, n = mkn
     fn = _pallas_fn("masked_matmul_dx")
-    _compile(fn, _spec((M, F), jnp.float32, one_chip),
-             _spec((D, F), jnp.float32, one_chip))
+    _compile(fn, _spec((m, n), jnp.float32, one_chip),
+             _spec((k, n), jnp.float32, one_chip))
 
 
-def test_masked_matmul_dw_compiles(one_chip, no_persistent_cache):
+@pytest.mark.parametrize("mkn", MATMULS.values(), ids=MATMULS.keys())
+def test_masked_matmul_dw_compiles(one_chip, no_persistent_cache, mkn):
+    m, k, n = mkn
     fn = _pallas_fn("masked_matmul_dw")
-    _compile(fn, _spec((M, D), jnp.float32, one_chip),
-             _spec((M, F), jnp.float32, one_chip))
+    _compile(fn, _spec((m, k), jnp.float32, one_chip),
+             _spec((m, n), jnp.float32, one_chip))
 
 
 def test_stochastic_round_compiles(one_chip, no_persistent_cache):
