@@ -19,7 +19,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import SpringContext, dense_apply, dense_init, rope_apply
+from repro.models.layers import (
+    SpringContext,
+    YarnSpec,
+    dense_apply,
+    dense_init,
+    rmsnorm_apply,
+    rmsnorm_init,
+    rope_apply,
+    yarn_mscale,
+)
 from repro.runtime.sharding import constrain
 
 Q_CHUNK = 1024
@@ -86,18 +95,21 @@ def _chunked_attention(
     causal: bool,
     window: Optional[int],
     q_chunk: int = Q_CHUNK,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Dense-math attention, scanned over query chunks to bound memory.
 
     Peak live intermediate is (B, H, q_chunk, S_kv_band) — for 32k prefill
-    at q_chunk=1024 that is ~1/32 of the full score matrix.
+    at q_chunk=1024 that is ~1/32 of the full score matrix.  The softmax
+    scale defaults to 1/sqrt(D).
     """
     b, s, h, d = q.shape
     skv = k.shape[1]  # != s for cross-attention (whisper decoder->encoder)
     dv = v.shape[-1]  # may differ from d (MLA: qk 192, v 128)
     kv_heads = k.shape[2]
     group = h // kv_heads
-    scale = 1.0 / (d**0.5)
+    if scale is None:
+        scale = 1.0 / (d**0.5)
     qc = q_chunk if s % q_chunk == 0 else s  # fall back for odd small seqs
     nchunks = s // qc
 
@@ -295,6 +307,21 @@ class MLASpec:
     qk_rope_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnSpec] = None
+
+
+def mla_softmax_scale(spec: MLASpec) -> float:
+    """(dn + dr)^-0.5, times mscale(factor, mscale_all_dim)^2 under YaRN."""
+    scale = (spec.qk_nope_dim + spec.qk_rope_dim) ** -0.5
+    if spec.rope_scaling is not None:
+        y = spec.rope_scaling
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x: jax.Array, positions: jax.Array, spec: MLASpec) -> jax.Array:
+    return rope_apply(x, positions, spec.rope_theta, yarn=spec.rope_scaling,
+                      interleaved=True)
 
 
 def mla_init(key, d: int, spec: MLASpec):
@@ -303,6 +330,7 @@ def mla_init(key, d: int, spec: MLASpec):
     return {
         "wq": dense_init(kq, d, h * (spec.qk_nope_dim + spec.qk_rope_dim)),
         "wdkv": dense_init(kkv, d, spec.kv_lora_rank),
+        "kv_norm": rmsnorm_init(spec.kv_lora_rank),
         "wkr": dense_init(kr, d, spec.qk_rope_dim),
         "wuk": dense_init(kuk, spec.kv_lora_rank, h * spec.qk_nope_dim),
         "wuv": dense_init(kuv, spec.kv_lora_rank, h * spec.v_head_dim),
@@ -320,54 +348,58 @@ def mla_apply(
     pos: Optional[jax.Array] = None,
     return_cache: bool = False,
 ):
-    """cache: {"ckv": (B, S, rank), "krope": (B, S, dr)}; pos = decode slot."""
+    """cache: {"ckv": (B, S, rank), "krope": (B, S, dr)}; pos = decode slot.
+
+    DeepSeek-V2's MLA: the compressed kv is RMS-normed (eps 1e-6) before its
+    up-projections (the cache holds the normed latent), rope (YaRN where the
+    spec scales it) runs on interleaved pairs, and the softmax scale is
+    :func:`mla_softmax_scale`.  Everything between the projections runs
+    under ``jax.named_scope("spring_mla_attention")``; the projections
+    themselves are spring matmuls."""
     b, s, _ = x.shape
     h, dn, dr, dv = spec.n_heads, spec.qk_nope_dim, spec.qk_rope_dim, spec.v_head_dim
     rank = spec.kv_lora_rank
-    scale = 1.0 / ((dn + dr) ** 0.5)
+    scale = mla_softmax_scale(spec)
 
     q = dense_apply(params["wq"], x, ctx, w_logical=("w_embed", "w_qkv")).reshape(b, s, h, dn + dr)
-    qn, qr = q[..., :dn], q[..., dn:]
-    qr = rope_apply(qr, positions, spec.rope_theta)
     ckv = dense_apply(params["wdkv"], x, ctx, w_logical=("w_embed", None))  # (B,S,rank)
-    krope = rope_apply(
-        dense_apply(params["wkr"], x, ctx, w_logical=("w_embed", None))[:, :, None, :],
-        positions, spec.rope_theta,
-    )[:, :, 0, :]  # (B, S, dr), shared across heads
+    kr = dense_apply(params["wkr"], x, ctx, w_logical=("w_embed", None))  # (B,S,dr)
 
-    wuk = params["wuk"]["kernel"].reshape(rank, h, dn)
-    wuv = params["wuv"]["kernel"].reshape(rank, h, dv)
+    with jax.named_scope("spring_mla_attention"):
+        qn, qr = q[..., :dn], _mla_rope(q[..., dn:], positions, spec)
+        ckv = rmsnorm_apply(params["kv_norm"], ckv)
+        krope = _mla_rope(kr[:, :, None, :], positions, spec)[:, :, 0, :]  # shared by heads
+        wuk = params["wuk"]["kernel"].reshape(rank, h, dn)
+        wuv = params["wuv"]["kernel"].reshape(rank, h, dv)
 
-    if cache is None:
-        # prefill: expand latent to per-head keys/values (standard form)
-        k_nope = jnp.einsum("bsr,rhd->bshd", ckv.astype(jnp.float32), wuk).astype(x.dtype)
-        vh = jnp.einsum("bsr,rhd->bshd", ckv.astype(jnp.float32), wuv).astype(x.dtype)
-        k_full = jnp.concatenate([k_nope, jnp.broadcast_to(krope[:, :, None, :], (b, s, h, dr)).astype(x.dtype)], -1)
-        q_full = jnp.concatenate([qn, qr], -1)
-        out = _chunked_attention(q_full, k_full, vh, causal=True, window=None)
-        out = out.reshape(b, s, h * dv)
-        new_cache = None
-        if return_cache:
-            new_cache = {"ckv": ckv.astype(jnp.bfloat16), "krope": krope.astype(jnp.bfloat16)}
-    else:
-        assert s == 1
-        pos_v = _pos_vec(pos, b)
-        ck = _row_update(cache["ckv"], ckv, pos_v)
-        cr = _row_update(cache["krope"], krope, pos_v)
-        # absorbed decode: project q into the latent space, attend in latent
-        q_lat = jnp.einsum("bhd,rhd->bhr", qn[:, 0].astype(jnp.float32), wuk)  # (B,H,rank)
-        s_lat = jnp.einsum("bhr,bsr->bhs", q_lat, ck.astype(jnp.float32))
-        s_rope = jnp.einsum("bhd,bsd->bhs", qr[:, 0].astype(jnp.float32), cr.astype(jnp.float32))
-        scores = (s_lat + s_rope) * scale
-        valid = jnp.arange(ck.shape[1])[None, :] <= pos_v[:, None]
-        scores = jnp.where(valid[:, None, :], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1)
-        ctx_lat = jnp.einsum("bhs,bsr->bhr", p, ck.astype(jnp.float32))
-        out = jnp.einsum("bhr,rhd->bhd", ctx_lat, wuv).reshape(b, 1, h * dv).astype(x.dtype)
-        new_cache = {"ckv": ck, "krope": cr}
+        if cache is None:
+            # prefill: expand latent to per-head keys/values (standard form)
+            k_nope = jnp.einsum("bsr,rhd->bshd", ckv.astype(jnp.float32), wuk).astype(x.dtype)
+            vh = jnp.einsum("bsr,rhd->bshd", ckv.astype(jnp.float32), wuv).astype(x.dtype)
+            k_full = jnp.concatenate([k_nope, jnp.broadcast_to(krope[:, :, None, :], (b, s, h, dr)).astype(x.dtype)], -1)
+            q_full = jnp.concatenate([qn, qr], -1)
+            out = _chunked_attention(q_full, k_full, vh, causal=True, window=None, scale=scale)
+            out = out.reshape(b, s, h * dv)
+            new_cache = None
+            if return_cache:
+                new_cache = {"ckv": ckv.astype(jnp.bfloat16), "krope": krope.astype(jnp.bfloat16)}
+        else:
+            assert s == 1
+            pos_v = _pos_vec(pos, b)
+            ck = _row_update(cache["ckv"], ckv, pos_v)
+            cr = _row_update(cache["krope"], krope, pos_v)
+            # absorbed decode: project q into the latent space, attend in latent
+            q_lat = jnp.einsum("bhd,rhd->bhr", qn[:, 0].astype(jnp.float32), wuk)  # (B,H,rank)
+            s_lat = jnp.einsum("bhr,bsr->bhs", q_lat, ck.astype(jnp.float32))
+            s_rope = jnp.einsum("bhd,bsd->bhs", qr[:, 0].astype(jnp.float32), cr.astype(jnp.float32))
+            scores = (s_lat + s_rope) * scale
+            valid = jnp.arange(ck.shape[1])[None, :] <= pos_v[:, None]
+            scores = jnp.where(valid[:, None, :], scores, -1e30)
+            p = jax.nn.softmax(scores, axis=-1)
+            ctx_lat = jnp.einsum("bhs,bsr->bhr", p, ck.astype(jnp.float32))
+            out = jnp.einsum("bhr,rhd->bhd", ctx_lat, wuv).reshape(b, 1, h * dv).astype(x.dtype)
+            new_cache = {"ckv": ck, "krope": cr}
 
-    # (prefill path: _chunked_attention scales by 1/sqrt(dn+dr) internally,
-    #  matching the decode path's explicit ``scale``.)
     out = dense_apply(params["wo"], out, ctx, w_logical=("w_qkv", "w_embed"),
                       out_logical=("batch", "seq", "embed"))
     return out, new_cache

@@ -166,14 +166,65 @@ def layernorm_apply(params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 # --------------------------------------------------------------------------
 
 
-def rope_apply(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (B, S, H, D) with D even; positions: (B, S) int32."""
+@dataclasses.dataclass(frozen=True)
+class YarnSpec:
+    """YaRN rope scaling (arXiv:2309.00071), in the fields of DeepSeek-V2's
+    ``rope_scaling``: frequencies blended between extrapolated (the
+    original) and interpolated (divided by ``factor``) along a linear
+    ramp between the correction dims of ``beta_fast`` and ``beta_slow``
+    rotations over ``original_max_position``; cos and sin scaled by
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_ramp(d: int, theta: float, yarn: YarnSpec) -> list:
+    """Per frequency (d/2 of them) the share taken from the interpolated
+    frequency: 0 below the correction dim of ``beta_fast``, 1 above that
+    of ``beta_slow``, linear between."""
+    def dim(rotations):
+        return d * math.log(yarn.original_max_position / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), d - 1)
+    high = high + 0.001 if high == low else high
+    return [min(max((i - low) / (high - low), 0.0), 1.0) for i in range(d // 2)]
+
+
+def rope_apply(x: jax.Array, positions: jax.Array, theta: float = 10000.0, *,
+               yarn: Optional[YarnSpec] = None, interleaved: bool = False) -> jax.Array:
+    """x: (B, S, H, D) with D even; positions: (B, S) int32.
+
+    Rotates the halves of D (NeoX layout).  ``interleaved`` takes the
+    pairs (2i, 2i+1) instead, as DeepSeek-V2 does: it de-interleaves them
+    into halves first, so the output is in the halves layout (q and k
+    alike, which leaves their dot product unchanged)."""
     d = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    mscale = 1.0
+    if yarn is not None:
+        ramp = jnp.asarray(yarn_ramp(d, theta, yarn), jnp.float32)
+        inv_freq = inv_freq / yarn.factor * ramp + inv_freq * (1.0 - ramp)
+        mscale = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # (B,S,D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        xf = jnp.concatenate([xf[..., 0::2], xf[..., 1::2]], axis=-1)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 

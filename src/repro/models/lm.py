@@ -71,6 +71,9 @@ class LMConfig:
     moe: Optional[MoESpec] = None
     d_ff: int = 0
     norm: str = "rms"  # "rms" | "layer"
+    # MoE expert share: the layer holds experts [0, experts_held) of
+    # moe.n_experts (one chip's share of an expert-parallel layer); 0 = all
+    experts_held: int = 0
     mlp_bias: bool = False
     tie_embeddings: bool = False
     vlm_prefix_len: int = 0  # internvl2: image patch positions
@@ -110,6 +113,17 @@ def _norm_apply(cfg: LMConfig, p, x):
     return rmsnorm_apply(p, x) if cfg.norm == "rms" else layernorm_apply(p, x)
 
 
+def _no_stats() -> dict:
+    """A block's training side numbers: the MoE load-balancing loss
+    (unweighted) and ``moe_rows`` ``[live, buffer, dropped]``, summed over
+    layers (zero where no MoE runs)."""
+    return {"aux": jnp.zeros((), jnp.float32), "moe_rows": jnp.zeros((3,), jnp.float32)}
+
+
+def _add(a: dict, b: dict) -> dict:
+    return jax.tree_util.tree_map(jnp.add, a, b)
+
+
 def block_init(key, cfg: LMConfig, kind: tuple) -> dict:
     mixer, ffn = kind
     km, kf = jax.random.split(key)
@@ -133,7 +147,7 @@ def block_init(key, cfg: LMConfig, kind: tuple) -> dict:
         elif ffn == "gelu":
             p["ffn"] = gelu_mlp_init(kf, cfg.d_model, cfg.d_ff, bias=cfg.mlp_bias)
         elif ffn == "moe":
-            p["ffn"] = moe_mod.moe_init(kf, cfg.d_model, cfg.moe)
+            p["ffn"] = moe_mod.moe_init(kf, cfg.d_model, cfg.moe, cfg.experts_held)
         else:
             raise ValueError(ffn)
     return p
@@ -149,8 +163,10 @@ def block_apply(
     cache: Optional[dict] = None,
     pos: Optional[jax.Array] = None,
     return_cache: bool = False,
+    dropless: bool = False,
 ):
-    """Pre-norm residual block. Returns (x, new_cache, aux_loss)."""
+    """Pre-norm residual block. Returns (x, new_cache, stats) with stats
+    as :func:`_no_stats`; ``dropless`` (training) drops no MoE pair."""
     mixer, ffn = kind
     h = _norm_apply(cfg, params["norm1"], x)
     new_cache = None
@@ -166,7 +182,7 @@ def block_apply(
     else:
         raise ValueError(mixer)
     x = (x + out).astype(x.dtype)
-    aux = jnp.zeros((), jnp.float32)
+    stats = _no_stats()
     if ffn != "none":
         h = _norm_apply(cfg, params["norm2"], x)
         if ffn == "swiglu":
@@ -174,9 +190,10 @@ def block_apply(
         elif ffn == "gelu":
             x = (x + gelu_mlp_apply(params["ffn"], h, ctx)).astype(x.dtype)
         elif ffn == "moe":
-            out, aux = moe_mod.moe_apply(params["ffn"], h, ctx, cfg.moe)
+            out, aux, rows = moe_mod.moe_apply(params["ffn"], h, ctx, cfg.moe, dropless=dropless)
             x = (x + out).astype(x.dtype)
-    return constrain(x, ("batch", "seq", "embed")), new_cache, aux
+            stats = {"aux": aux, "moe_rows": rows}
+    return constrain(x, ("batch", "seq", "embed")), new_cache, stats
 
 
 def block_init_cache(cfg: LMConfig, kind: tuple, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -232,27 +249,30 @@ def lm_hidden(
     tokens: jax.Array,
     ctx: SpringContext,
     img_embeds: Optional[jax.Array] = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Token ids (B, S_text) [+ (B, P, d) image embeds] -> final hidden."""
+) -> tuple[jax.Array, dict]:
+    """Token ids (B, S_text) [+ (B, P, d) image embeds] -> (final hidden,
+    stats summed over layers, as :func:`_no_stats`).  This is the training
+    forward: MoE layers drop no pair."""
     x = embed_apply(params["embed"], tokens, ctx)
     if cfg.vlm_prefix_len:
         assert img_embeds is not None
         x = jnp.concatenate([img_embeds.astype(x.dtype), x], axis=1)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    aux = jnp.zeros((), jnp.float32)
+    stats = _no_stats()
     for i, kind in enumerate(cfg.prefix):
-        x, _, a = block_apply(params[f"prefix_{i}"], x, ctx, cfg, kind, positions)
-        aux += a
+        x, _, a = block_apply(params[f"prefix_{i}"], x, ctx, cfg, kind, positions, dropless=True)
+        stats = _add(stats, a)
     if cfg.n_units > 0:
         # scan over units; each scan step applies the unit's kinds in order
         # (so interleaved patterns like (rec, rec, local) keep layer order)
         def body(carry, unit_params):
             h, aux_c = carry
             for u, kind in enumerate(cfg.pattern_unit):
-                h, _, a = block_apply(unit_params[u], h, ctx, cfg, kind, positions)
+                h, _, a = block_apply(unit_params[u], h, ctx, cfg, kind, positions,
+                                      dropless=True)
                 h = checkpoint_name(h, "block_out")
-                aux_c += a
+                aux_c = _add(aux_c, a)
             return (h, aux_c), None
 
         # memstash resolution: remat_policy="stash" nominates the residual
@@ -291,9 +311,10 @@ def lm_hidden(
                         ctx, keys=KeyGen(k) if k is not None else ctx.keys,
                         tile_probe=probe)
                     for u, kind in enumerate(cfg.pattern_unit):
-                        h_, _, a = block_apply(up[u], h_, ctx_u, cfg, kind, pos)
+                        h_, _, a = block_apply(up[u], h_, ctx_u, cfg, kind, pos,
+                                               dropless=True)
                         h_ = checkpoint_name(h_, "block_out")
-                        aux_cc += a
+                        aux_cc = _add(aux_cc, a)
                     return h_, aux_cc
 
                 return stash_apply(unit, scfg, "lm/residual", h,
@@ -304,13 +325,13 @@ def lm_hidden(
         else:
             body_fn = body
         unit_stack = tuple(params[f"unit_{u}"] for u in range(len(cfg.pattern_unit)))
-        (x, aux), _ = jax.lax.scan(body_fn, (x, aux), unit_stack,
-                                   unroll=cfg.n_units if cfg.scan_unroll else 1)
+        (x, stats), _ = jax.lax.scan(body_fn, (x, stats), unit_stack,
+                                     unroll=cfg.n_units if cfg.scan_unroll else 1)
     for i, kind in enumerate(cfg.suffix):
-        x, _, a = block_apply(params[f"suffix_{i}"], x, ctx, cfg, kind, positions)
-        aux += a
+        x, _, a = block_apply(params[f"suffix_{i}"], x, ctx, cfg, kind, positions, dropless=True)
+        stats = _add(stats, a)
     x = _norm_apply(cfg, params["final_norm"], x)
-    return x, aux
+    return x, stats
 
 
 def _logits_kernel(params, cfg: LMConfig):
@@ -325,11 +346,12 @@ def lm_loss(
     tokens: jax.Array,
     ctx: SpringContext,
     img_embeds: Optional[jax.Array] = None,
-    aux_weight: float = 0.01,
 ) -> tuple[jax.Array, dict]:
     """Next-token CE, chunked over the sequence so the (tokens x vocab)
-    logits tensor never materializes whole (DESIGN.md §4)."""
-    h, aux = lm_hidden(params, cfg, tokens, ctx, img_embeds)
+    logits tensor never materializes whole (DESIGN.md §4), plus the MoE
+    load-balancing loss weighted by ``moe.aux_alpha``.  The metrics hold
+    ``ce``, ``aux`` and, for MoE configs, ``moe_rows``."""
+    h, stats = lm_hidden(params, cfg, tokens, ctx, img_embeds)
     if cfg.vlm_prefix_len:
         h = h[:, cfg.vlm_prefix_len :]  # loss over text positions only
     b, s, d = h.shape
@@ -340,8 +362,10 @@ def lm_loss(
         inputs_h, labels, _logits_kernel(params, cfg),
         logits_dtype=jnp.bfloat16 if cfg.bf16_logits else jnp.float32)
     ce = total / (b * n)
-    loss = ce + aux_weight * aux
-    return loss, {"ce": ce, "aux": aux}
+    if cfg.moe is None:
+        return ce, {"ce": ce, "aux": stats["aux"]}
+    loss = ce + cfg.moe.aux_alpha * stats["aux"]
+    return loss, {"ce": ce, **stats}
 
 
 # --------------------------------------------------------------------------

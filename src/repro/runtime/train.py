@@ -153,7 +153,9 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
     backward is in force: masked_matmul's ``[fwd_issued, fwd_total,
     dx_issued, dx_total, dw_issued, dw_total]`` 128-tile steps and its
     ``[one_dot, total]`` block grid steps of the step, float32 (exact
-    below 2**24).  The optimizer runs under
+    below 2**24); MoE configs add ``moe_rows`` (``[live, buffer,
+    dropped]`` rows of the held experts, every mode), summed like the
+    tiles over microbatches.  The optimizer runs under
     ``jax.named_scope("spring_optimizer")``."""
     cfg = arch.reduced() if reduced else arch.config
     _, opt_update = make_optimizer(step_cfg.optimizer)
@@ -208,7 +210,9 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
             body, (jnp.zeros((), jnp.float32), zero_g,
                    jnp.zeros((PROBE_SIZE,), jnp.float32), params), jnp.arange(nm)
         )
-        metrics = jax.tree_util.tree_map(lambda m: m[-1], metrics)
+        # counts sum over the microbatches; every other metric is the last's
+        metrics = {name: m.sum(0) if name == "moe_rows" else m[-1]
+                   for name, m in metrics.items()}
         return loss, metrics, grads, tiles if count_tiles else None
 
     def step_metrics(metrics, loss, om, tiles) -> dict:

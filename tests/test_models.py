@@ -114,6 +114,9 @@ def test_moe_capacity_and_balance_loss():
     key = jax.random.PRNGKey(0)
     params = moe_init(key, 16, spec)
     x = jax.random.normal(key, (2, 24, 16))
-    y, aux = moe_apply(params, x, SpringContext(), spec)
+    y, aux, rows = moe_apply(params, x, SpringContext(), spec)
     assert y.shape == x.shape and bool(jnp.all(jnp.isfinite(y)))
     assert float(aux) >= 1.0 - 1e-3  # >= 1 by Cauchy-Schwarz at any routing
+    # serving's capacity: 48 tokens x 2 slots over 8 experts, 15 rows each
+    live, buffer, dropped = (float(v) for v in rows)
+    assert buffer == 8 * 15 and live + dropped == 48 * 2

@@ -118,3 +118,47 @@ def test_step_names_its_device_phases():
     hlo = step.lower(state, batch).compile().as_text()
     for scope in SCOPES:
         assert f"/{scope}/" in hlo, scope
+
+
+LAYER_SCOPES = ("spring_moe_dispatch", "spring_moe_combine", "spring_mla_attention")
+
+
+@pytest.mark.parametrize("mode,microbatch", [
+    pytest.param("dense", None, id="dense"),
+    pytest.param("quant_sparse", None, id="quant_sparse"),
+    pytest.param("quant_sparse", 2, id="quant_sparse-microbatch"),
+])
+def test_moe_and_mla_phases_and_row_counter(mode, microbatch):
+    """DeepSeek-V2-Lite's reduced preset (MLA everywhere, a dense first
+    layer, 2 MoE layers holding 4 of 8 experts): the layer scopes reach
+    the compiled step, and ``moe_rows`` is counted in every mode, with
+    no pair dropped, and summed over microbatches."""
+    from repro.api.spec import build_spec
+    from repro.models.lm import lm_init
+    from repro.optim.optimizers import make_optimizer
+    from repro.runtime.train import TrainState, make_train_step
+
+    r = build_spec("train", use_env=False, sets=[
+        "arch.id=deepseek-v2-lite-16b", "arch.reduced=true", "shape.batch=2",
+        "shape.seq=32", f"numerics.mode={mode}", "sparsity.backward=auto"]
+        + ([f"shape.microbatch={microbatch}"] if microbatch else [])).resolve()
+    assert r.step.microbatch == microbatch
+    cfg = dataclasses.replace(r.config, experts_held=4)
+    params = lm_init(jax.random.PRNGKey(0), cfg)
+    opt_init, _ = make_optimizer(r.step.optimizer)
+    state = TrainState(params, opt_init(params), jnp.zeros((), jnp.int32),
+                       jax.random.PRNGKey(1), None)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (2, 32), 0, cfg.vocab)}
+    step = jax.jit(make_train_step(r.arch.view(config=cfg), r.step))
+    compiled = step.lower(state, batch).compile()
+    hlo = compiled.as_text()
+    for scope in LAYER_SCOPES:
+        assert f"/{scope}/" in hlo, scope
+    _, metrics = compiled(state, batch)
+    live, buffer, dropped = np.asarray(metrics["moe_rows"]).tolist()
+    # 2 layers of 64 tokens, 2 of 8 experts per token: quant_sparse holds 4
+    # experts x 64 token rows a layer (microbatches: 2 x 4 x 32), dense packs
+    # the held pairs into 64 x 2 rows
+    want_buffer = 2 * (4 * 64 if mode == "quant_sparse" else 64 * 2)
+    assert buffer == want_buffer and dropped == 0 and 0 < live <= 2 * 64 * 2
+    assert ("mm_tiles" in metrics) == (mode == "quant_sparse")
