@@ -277,8 +277,8 @@ def _conv_sb_bwd(stride, padding, bwd_impl, res, g):
     dx = masked_matmul_dx(pg, wt.T, impl=impl)
     # the tile counter (see spring_matmul): the forward is a plain conv,
     # so only the dx and dw calls count
-    counts = mm_ops.probe_counts(jnp.zeros((4,), jnp.float32),
-                                 mm_ops.call_counts(pg, wt),
+    dx_counts = mm_ops.call_counts(pg, wt)
+    counts = mm_ops.probe_counts(jnp.zeros_like(dx_counts), dx_counts,
                                  mm_ops.call_counts(p.T, g2))
     return dx.reshape(n, h, wd, cin), dw, counts
 

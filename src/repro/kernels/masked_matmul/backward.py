@@ -69,7 +69,7 @@ def _blocked_dot(a: jax.Array, b: jax.Array) -> jax.Array:
     SPRING's tile-AND gate.  Tiles whose joint occupancy is empty are
     multiplied by a 0.0 gate, contributing exactly +0.0 to the fp32
     accumulator — same numerics contract as the Pallas kernel's skip."""
-    ap, bp, a_occ, b_occ, _, _ = mm_ops.prepare(a, b)  # (Mi, Kk), (Kk, Nj)
+    ap, bp, a_occ, b_occ, _, _, _ = mm_ops.prepare(a, b)  # (Mi, Kk), (Kk, Nj)
     (m_pad, k_pad), n_pad = ap.shape, bp.shape[1]
     with jax.named_scope("spring_mm_prep"):
         at = ap.reshape(m_pad // TILE, TILE, k_pad // TILE, TILE).transpose(0, 2, 1, 3)
@@ -153,6 +153,13 @@ def _dx_examples() -> list:
     cases.append(((_sparse_mat(6, (512, 384), 0.2), _sparse_mat(7, (512, 384), 0.2)), {}))
     g = _sparse_mat(8, (256, 512), 0.2).at[128:, :128].set(0.0)
     cases.append(((g, _sparse_mat(9, (384, 512), 0.2)), {}))
+    # idle block steps: cotangent rows live in row blocks 1 and 3 of five
+    # (leading, interior and trailing idle runs), and an all-empty one
+    g = _sparse_mat(10, (1280, 384), 0.2)
+    for lo, hi in ((0, 256), (320, 768), (896, 1280)):
+        g = g.at[lo:hi].set(0.0)
+    cases.append(((g, _sparse_mat(11, (768, 384), 0.2)), {}))
+    cases.append(((jnp.zeros((1024, 256)), _sparse_mat(12, (384, 256), 0.2)), {}))
     return cases
 
 
@@ -170,6 +177,12 @@ def _dw_examples() -> list:
     cases.append(((_sparse_mat(6, (384, 512), 0.2), _sparse_mat(7, (384, 768), 0.2)), {}))
     x = _sparse_mat(8, (256, 1024), 0.2).at[:, :512].set(0.0)
     cases.append(((x, _sparse_mat(9, (256, 256), 0.2)), {}))
+    # an expert buffer cut to 128-multiples: rows 0-383 of 2048 live, so
+    # the empty blocks lie along the kernel's K and k-steps 1-3 are idle;
+    # and an all-empty activation
+    x = _sparse_mat(10, (2048, 512), 0.2).at[384:].set(0.0)
+    cases.append(((x, _sparse_mat(11, (2048, 384), 0.2).at[384:].set(0.0)), {}))
+    cases.append(((jnp.zeros((1024, 256)), _sparse_mat(12, (1024, 384), 0.2)), {}))
     return cases
 
 
@@ -250,8 +263,8 @@ def _float0_zero(seed: jax.Array):
 
 #: Length of the tile probe: the ``[issued, total]`` 128-tile steps of
 #: the forward, dx and dw calls, in that order, then their ``[one_dot,
-#: total]`` block grid steps summed (see ``ops.probe_counts``).
-PROBE_SIZE = 8
+#: total, idle]`` block grid steps summed (see ``ops.probe_counts``).
+PROBE_SIZE = 9
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -352,9 +365,9 @@ def mm_call_with_backward(
     ``probe`` is the tile counter: a float32 vector of ``PROBE_SIZE`` that
     the result does not depend on; its gradient is this call's
     ``[fwd_issued, fwd_total, dx_issued, dx_total, dw_issued, dw_total,
-    one_dot_blocks, blocks]`` (see ``ops.probe_counts``).  Differentiating
-    a whole program with respect to one probe shared by every call sums
-    them, under ``scan``, remat and ``jit`` alike.
+    one_dot_blocks, blocks, idle_blocks]`` (see ``ops.probe_counts``).
+    Differentiating a whole program with respect to one probe shared by
+    every call sums them, under ``scan``, remat and ``jit`` alike.
 
     A concrete ``bwd_impl`` is validated eagerly so a bad pin fails at the
     call site, not inside the backward trace.

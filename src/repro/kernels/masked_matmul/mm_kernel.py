@@ -18,6 +18,14 @@ module + MAC lanes (paper Figs. 6-8, DESIGN.md §2/P1):
     lifted to tile granularity.  All-zero tiles cost no MXU work
     ("ineffectual computations are completely skipped"), exactly the
     tiles a grid of single 128-tiles would skip.
+  * An *idle* block step, one whose sub-tiles all fail the gate, costs
+    no copies either: a prefetched ``fetch`` table points its x and w
+    index maps at the blocks the previous step already holds, and the
+    Pallas pipeline issues no DMA for a block index that does not
+    change.  The body never reads an operand on an idle step (it skips
+    the sub-tile loop where ``fetch`` names another step), so the
+    redirected blocks do not touch the result.  Where every step works,
+    ``fetch`` is the identity and the copies are the plain grid's.
   * The epilogue applies stochastic rounding (paper Eq. 4) back to
     Q(IL,FL) using the same counter-based xorshift stream as
     ``kernels/stochastic_round``; the counter is the element's position
@@ -57,18 +65,28 @@ def block_dims(m_pad: int, n_pad: int, k_pad: int) -> tuple[int, int, int]:
     return tuple(next(b for b in BLOCKS if d % b == 0) for d in (m_pad, n_pad, k_pad))
 
 
+def fetch_bits(n_blocks: int, k_steps: int) -> tuple[int, int]:
+    """Bits of the J and K fields of a packed grid step ``(I << (jb + kb))
+    | (J << kb) | K`` on a grid of ``n_blocks`` x ``k_steps``: the index
+    maps unpack it with shifts and masks, no division."""
+    return (n_blocks - 1).bit_length(), (k_steps - 1).bit_length()
+
+
 def _mm_kernel(
     xo_ref,
     wo_ref,
     xf_ref,
     wf_ref,
     seed_ref,
+    fetch_ref,
     x_ref,
     w_ref,
     out_ref,
     *,
     k_steps: int,
     n_blocks: int,
+    j_bits: int,
+    k_bits: int,
     k_tiles: int,
     n_tiles: int,
     n_pad: int,
@@ -86,6 +104,10 @@ def _mm_kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
     full = (xf_ref[i * k_steps + k] & wf_ref[k * n_blocks + j]) != 0
+    # a step whose fetch names another step's blocks is idle: no sub-tile
+    # passes the gate, so its loop is skipped too
+    own = (i << (j_bits + k_bits)) | (j << k_bits) | k
+    works = fetch_ref[(i * n_blocks + j) * k_steps + k] == own
 
     @pl.when(full)
     def _block():
@@ -95,7 +117,7 @@ def _mm_kernel(
 
     if rm * rn * rk > 1:  # a one-tile block is full exactly when occupied
 
-        @pl.when(jnp.logical_not(full))
+        @pl.when(jnp.logical_not(full) & works)
         def _tiles():
             def sub_tile(t, carry):
                 a, b, c = t // (rn * rk), (t // rk) % rn, t % rk
@@ -142,6 +164,7 @@ def masked_matmul_pallas(
     w_occ: jax.Array,
     x_full: jax.Array,
     w_full: jax.Array,
+    fetch: jax.Array,
     seed: jax.Array,
     *,
     il: int = 4,
@@ -153,8 +176,12 @@ def masked_matmul_pallas(
 
     x_occ: (M/TILE, K/TILE) and w_occ: (K/TILE, N/TILE) int32 per tile;
     x_full: (M/bm, K/bk) and w_full: (K/bk, N/bn) int32 per block of
-    :func:`block_dims`.  The four tables and the seed are
-    scalar-prefetched into SMEM (flattened row-major).
+    :func:`block_dims`; fetch: int32 per grid step, row-major over
+    (M/bm, N/bn, K/bk), the :func:`fetch_bits`-packed step whose x and w
+    blocks that step loads (the step itself where it issues a dot, else a
+    step whose blocks the previous step already holds, so an idle step
+    copies nothing).  The five tables and the seed are scalar-prefetched
+    into SMEM (flattened row-major).
     """
     m, k = x.shape
     k2, n = w.shape
@@ -162,11 +189,31 @@ def masked_matmul_pallas(
     bm, bn, bk = block_dims(m, n, k)
     grid = (m // bm, n // bn, k // bk)
     assert x_full.shape == (grid[0], grid[2]) and w_full.shape == (grid[2], grid[1])
+    assert fetch.size == grid[0] * grid[1] * grid[2]
+    nj, kk = grid[1], grid[2]
+    jb, kb = fetch_bits(nj, kk)
+    assert (grid[0] - 1).bit_length() + jb + kb <= 31
+
+    def fetched(i, j, k, *tables):
+        """(I', J', K') of the blocks grid step (i, j, k) loads."""
+        f = tables[5][(i * nj + j) * kk + k]
+        return f >> (jb + kb), (f >> kb) & ((1 << jb) - 1), f & ((1 << kb) - 1)
+
+    def x_map(i, j, k, *tables):
+        fi, _, fk = fetched(i, j, k, *tables)
+        return fi, fk
+
+    def w_map(i, j, k, *tables):
+        _, fj, fk = fetched(i, j, k, *tables)
+        return fk, fj
+
     eps = 2.0**-fl
     kernel = functools.partial(
         _mm_kernel,
         k_steps=grid[2],
         n_blocks=grid[1],
+        j_bits=jb,
+        k_bits=kb,
         k_tiles=k // TILE,
         n_tiles=n // TILE,
         n_pad=n,
@@ -181,13 +228,10 @@ def masked_matmul_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk, *_: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk, *_: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, *_: (i, j)),
+        in_specs=[pl.BlockSpec((bm, bk), x_map), pl.BlockSpec((bk, bn), w_map)],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
     )
     return pl.pallas_call(
         kernel,
@@ -201,6 +245,7 @@ def masked_matmul_pallas(
         x_full.astype(jnp.int32).reshape(-1),
         w_full.astype(jnp.int32).reshape(-1),
         seed.astype(jnp.uint32).reshape(1),
+        fetch.astype(jnp.int32).reshape(-1),
         x,
         w,
     )

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import registry
 from repro.kernels.masked_matmul.mm_kernel import (
-    TILE, block_dims, masked_matmul_pallas, padded_dims)
+    TILE, block_dims, fetch_bits, masked_matmul_pallas, padded_dims)
 from repro.kernels.masked_matmul.ref import masked_matmul_reference
 
 
@@ -35,12 +35,40 @@ def _full(occ: jax.Array, rm: int, rn: int) -> jax.Array:
     return jnp.all(occ.reshape(m // rm, rm, n // rn, rn) != 0, axis=(1, 3)).astype(jnp.int32)
 
 
+def _block_work(x_occ: jax.Array, w_occ: jax.Array, rm: int, rn: int,
+                rk: int) -> jax.Array:
+    """(MI, NJ, KK) bool: True where the kernel's block grid step (I, J, K)
+    issues a dot, i.e. some 128-sub-tile has ``x_occ & w_occ``."""
+    (mi, kk), nj = x_occ.shape, w_occ.shape[1]
+    x_any = jnp.any(x_occ.reshape(mi // rm, rm, kk // rk, rk) != 0, axis=1)
+    w_any = jnp.any(w_occ.reshape(kk // rk, rk, nj // rn, rn) != 0, axis=3)
+    return jnp.einsum("ikc,kcj->ijk", x_any.astype(jnp.float32),
+                      w_any.astype(jnp.float32)) > 0
+
+
+def _fetch(work: jax.Array) -> jax.Array:
+    """Int32 per grid step in row-major (I, J, K) order: the
+    :func:`fetch_bits`-packed (I', J', K') of the last working step at or
+    before it, else of the first working step ((0, 0, 0) if none works).
+    An idle step thus names the blocks its predecessor holds.  Packing
+    keeps row-major order, so a running max finds the last working step."""
+    _, nj, kk = work.shape
+    jb, kb = fetch_bits(nj, kk)
+    i, j, k = (jax.lax.broadcasted_iota(jnp.int32, work.shape, d) for d in range(3))
+    code = ((i << (jb + kb)) | (j << kb) | k).reshape(-1)
+    flat = work.reshape(-1)
+    last = jax.lax.cummax(jnp.where(flat, code, -1))
+    return jnp.where(last >= 0, last, code[jnp.argmax(flat)])
+
+
 def prepare(x: jax.Array, w: jax.Array):
     """The pre-compute sparsity stage of ``x @ w``: both operands padded to
     MXU tiles in float32, their occupancy tables ``x_occ`` (Mi, Kk) and
-    ``w_occ`` (Kk, Nj), int32 (1 where a tile holds a non-zero), and the
+    ``w_occ`` (Kk, Nj), int32 (1 where a tile holds a non-zero), the
     kernel's block tables ``x_full`` (MI, KK) and ``w_full`` (KK, NJ),
-    int32 (1 where every tile of a :func:`block_dims` block is occupied)."""
+    int32 (1 where every tile of a :func:`block_dims` block is occupied),
+    and ``fetch`` (MI * NJ * KK,), int32, the packed grid step whose
+    operand blocks each step loads (:func:`_fetch`)."""
     with jax.named_scope("spring_mm_prep"):
         m, k = x.shape
         _, n = w.shape
@@ -50,7 +78,9 @@ def prepare(x: jax.Array, w: jax.Array):
         wp = jnp.pad(w.astype(jnp.float32), ((0, k_pad - k), (0, n_pad - n)))
         x_occ, w_occ = _occupancy(xp, TILE, TILE), _occupancy(wp, TILE, TILE)
         rm, rn, rk = bm // TILE, bn // TILE, bk // TILE
-        return xp, wp, x_occ, w_occ, _full(x_occ, rm, rk), _full(w_occ, rk, rn)
+        work = _block_work(x_occ, w_occ, rm, rn, rk)
+        return (xp, wp, x_occ, w_occ, _full(x_occ, rm, rk), _full(w_occ, rk, rn),
+                _fetch(work))
 
 
 def _pair_count(a_table: jax.Array, b_table: jax.Array) -> jax.Array:
@@ -66,18 +96,24 @@ def tile_counts(x: jax.Array, w: jax.Array) -> jax.Array:
     padding tiles count.  Built from :func:`prepare`, so inside a jitted
     program the tables are the ones the kernel's wrapper builds (XLA
     merges the two identical computations)."""
-    _, _, x_occ, w_occ, _, _ = prepare(x, w)
+    _, _, x_occ, w_occ, _, _, _ = prepare(x, w)
     with jax.named_scope("spring_mm_prep"):
         return _pair_count(x_occ, w_occ)
 
 
 def block_counts(x: jax.Array, w: jax.Array) -> jax.Array:
-    """``[one_dot, total]`` block grid steps of the kernel on ``x @ w`` as
-    float32: a block step (I, J, K) takes the one-dot path when
-    ``x_full[I, K] AND w_full[K, J]``, from :func:`prepare`'s tables."""
-    _, _, _, _, x_full, w_full = prepare(x, w)
+    """``[one_dot, total, idle]`` block grid steps of the kernel on ``x @ w``
+    as float32: a block step (I, J, K) takes the one-dot path when
+    ``x_full[I, K] AND w_full[K, J]``, and is idle (issues no dot, so its
+    operand copies are elided) where :func:`_block_work` is False, from
+    :func:`prepare`'s tables."""
+    _, _, x_occ, w_occ, x_full, w_full, _ = prepare(x, w)
     with jax.named_scope("spring_mm_prep"):
-        return _pair_count(x_full, w_full)
+        rm, rk = x_occ.shape[0] // x_full.shape[0], x_occ.shape[1] // x_full.shape[1]
+        rn = w_occ.shape[1] // w_full.shape[1]
+        work = _block_work(x_occ, w_occ, rm, rn, rk)
+        idle = jnp.float32(work.size) - jnp.sum(work, dtype=jnp.float32)
+        return jnp.concatenate([_pair_count(x_full, w_full), idle[None]])
 
 
 def call_counts(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -88,8 +124,8 @@ def call_counts(x: jax.Array, w: jax.Array) -> jax.Array:
 def probe_counts(fwd: jax.Array, dx: jax.Array, dw: jax.Array) -> jax.Array:
     """The tile probe's cotangent from the :func:`call_counts` of one
     ``x @ w``'s forward, dx and dw calls: ``[fwd_issued, fwd_total,
-    dx_issued, dx_total, dw_issued, dw_total, one_dot_blocks, blocks]``,
-    the block counts summed over the three calls."""
+    dx_issued, dx_total, dw_issued, dw_total, one_dot_blocks, blocks,
+    idle_blocks]``, the block counts summed over the three calls."""
     return jnp.concatenate([fwd[:2], dx[:2], dw[:2], fwd[2:] + dx[2:] + dw[2:]])
 
 
@@ -136,6 +172,15 @@ def _examples() -> list:
     cases.append(((x, w, jnp.uint32(7)), {}))
     cases.append(((x, w, jnp.uint32(7)), {"apply_sr": False},
                   {"kind": "allclose", "atol": 1e-6, "rtol": 0.0}))
+    # idle block steps load the previous step's blocks: live rows in row
+    # blocks 1 and 3 of five 256-row blocks (leading, interior and
+    # trailing idle runs, over two k-steps), and an all-empty operand
+    x = _example_operands(4, (1280, 768), 0.3)
+    for lo, hi in ((0, 256), (320, 768), (896, 1280)):
+        x = x.at[lo:hi].set(0.0)
+    cases.append(((x, _example_operands(5, (768, 384), 0.3), jnp.uint32(9)), {}))
+    cases.append(((jnp.zeros((1024, 256)), _example_operands(6, (256, 384), 0.3),
+                   jnp.uint32(11)), {}))
     return cases
 
 

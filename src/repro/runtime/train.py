@@ -152,7 +152,7 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
     The step's metrics hold ``mm_tiles`` where the sparsity-aware
     backward is in force: masked_matmul's ``[fwd_issued, fwd_total,
     dx_issued, dx_total, dw_issued, dw_total]`` 128-tile steps and its
-    ``[one_dot, total]`` block grid steps of the step, float32 (exact
+    ``[one_dot, total, idle]`` block grid steps of the step, float32 (exact
     below 2**24); MoE configs add ``moe_rows`` (``[live, buffer,
     dropped]`` rows of the held experts, every mode), summed like the
     tiles over microbatches.  The optimizer runs under
@@ -164,7 +164,7 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
     # masked_matmul's tile counter: where the sparsity-aware backward is in
     # force, the loss is differentiated against a zero probe as well, whose
     # gradient sums every call's [fwd, dx, dw] x [issued, total] tile steps
-    # and its [one_dot, total] block grid steps
+    # and its [one_dot, total, idle] block grid steps
     count_tiles = spring_cfg.sparse_backward
 
     def ctx_for(key, probe=None) -> SpringContext:

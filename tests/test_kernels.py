@@ -11,8 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.masked_matmul.mm_kernel import BLOCKS, TILE, block_dims, padded_dims
-from repro.kernels.masked_matmul.ops import masked_matmul, tile_skip_fraction
+from repro.kernels.masked_matmul.mm_kernel import (
+    BLOCKS, TILE, block_dims, fetch_bits, padded_dims)
+from repro.kernels.masked_matmul.ops import (
+    block_counts, masked_matmul, prepare, tile_skip_fraction)
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.stochastic_round.ops import stochastic_round
 
@@ -65,6 +67,87 @@ def test_masked_matmul_blocks_divide_and_fit():
             assert b % TILE == 0 and d % b == 0 and b <= max(BLOCKS)
     bm = bn = bk = max(BLOCKS)
     assert 2 * 4 * (bm * bk + bk * bn + bm * bn) <= 6 * 2**20 < 16 * 2**20
+
+
+def _grid_fetch(x, w):
+    """Brute force over the kernel's grid in row-major (I, J, K) order:
+    whether each block step issues a dot (some 128-sub-tile with x and w
+    both occupied), and the step whose blocks it loads (the last working
+    step at or before it, else the first working step, else 0)."""
+    x, w = np.asarray(x), np.asarray(w)
+    (m, k), n = x.shape, w.shape[1]
+    m_pad, n_pad, k_pad = padded_dims(m, n, k)
+    bm, bn, bk = block_dims(m_pad, n_pad, k_pad)
+    xp = np.zeros((m_pad, k_pad))
+    xp[:m, :k] = x
+    wp = np.zeros((k_pad, n_pad))
+    wp[:k, :n] = w
+
+    def occupied(a, r, c):
+        return bool(np.any(a[r * TILE:(r + 1) * TILE, c * TILE:(c + 1) * TILE] != 0))
+
+    work = []
+    for bi in range(m_pad // bm):
+        for bj in range(n_pad // bn):
+            for bkk in range(k_pad // bk):
+                work.append(any(
+                    occupied(xp, bi * bm // TILE + a, bkk * bk // TILE + c)
+                    and occupied(wp, bkk * bk // TILE + c, bj * bn // TILE + b)
+                    for a in range(bm // TILE) for b in range(bn // TILE)
+                    for c in range(bk // TILE)))
+    first = work.index(True) if any(work) else 0
+    fetch, last = [], None
+    for s, works in enumerate(work):
+        last = s if works else last
+        fetch.append(first if last is None else last)
+    return work, fetch
+
+
+def _fetch_cases():
+    full = qgrid(10, (1024, 768), 0.0) + 1.0
+    between = qgrid(11, (2560, 768), 0.3)
+    # live rows 512:640 and 1536:1664: row blocks 1 and 3 of 5
+    for lo, hi in ((0, 512), (640, 1536), (1664, 2560)):   # leading, interior, trailing
+        between = between.at[lo:hi].set(0.0)
+    expert = qgrid(12, (2048, 512), 0.3).at[384:].set(0.0)
+    return {
+        "full": (full, qgrid(13, (768, 1024), 0.0) + 1.0),
+        "rows_between_empty": (between, qgrid(14, (768, 768), 0.3)),
+        "empty_k_blocks": (expert.T, qgrid(15, (2048, 384), 0.3).at[384:].set(0.0)),
+        "all_empty": (jnp.zeros((1024, 256)), qgrid(16, (256, 384), 0.3)),
+        "w_tile_holes": (qgrid(17, (512, 1024), 0.3),
+                         qgrid(18, (1024, 768), 0.3).at[:512, :384].set(0.0)),
+    }
+
+
+@pytest.mark.parametrize("case", list(_fetch_cases()))
+def test_masked_matmul_fetch_table(case):
+    """``prepare``'s fetch table against a loop over the grid: a working
+    step loads its own blocks, an idle step the previous step's (so the
+    pipeline copies nothing for it), fully occupied operands give the
+    identity; ``block_counts``' idle entry counts the idle steps."""
+    x, w = _fetch_cases()[case]
+    work, want = _grid_fetch(x, w)
+    tables = prepare(x, w)
+    nj, kk = tables[5].shape[1], tables[4].shape[1]
+    jb, kb = fetch_bits(nj, kk)
+    # unpack (I', J', K') into the flat row-major step
+    fetch = [((f >> (jb + kb)) * nj + ((f >> kb) & ((1 << jb) - 1))) * kk
+             + (f & ((1 << kb) - 1)) for f in np.asarray(tables[6]).tolist()]
+    assert fetch == want
+    for s, works in enumerate(work):
+        if works:
+            assert fetch[s] == s
+        elif s > 0:
+            assert fetch[s] == fetch[s - 1]
+    idle = work.count(False)
+    assert np.asarray(block_counts(x, w))[1:].tolist() == [len(work), idle]
+    if case == "full":
+        assert idle == 0 and fetch == list(range(len(work)))
+    if case == "all_empty":
+        assert idle == len(work) and fetch == [0] * len(work)
+    if case in ("rows_between_empty", "empty_k_blocks"):
+        assert 0 < idle < len(work)
 
 
 def test_masked_matmul_grad_path():

@@ -51,8 +51,9 @@ def _block(tiles: int) -> int:
 
 def _counts(a, b) -> list:
     """[issued, total] 128x128 tile steps of ``a @ b``, padding included,
-    and [one_dot, total] block grid steps (blocks whose tiles are all
-    occupied in both operands), in numpy."""
+    and [one_dot, total, idle] block grid steps (one_dot: blocks whose
+    tiles are all occupied in both operands; idle: blocks where no tile
+    is occupied in both), in numpy."""
     a, b = np.asarray(a), np.asarray(b)
     (m, k), n = a.shape, b.shape[1]
     mi, ki, ni = -(-m // 128), -(-k // 128), -(-n // 128)
@@ -65,21 +66,24 @@ def _counts(a, b) -> list:
     rm, rk, rn = _block(mi), _block(ki), _block(ni)
     a_full = a_occ.reshape(mi // rm, rm, ki // rk, rk).all(axis=(1, 3))
     b_full = b_occ.reshape(ki // rk, rk, ni // rn, rn).all(axis=(1, 3))
-    return [int((a_occ[:, :, None] & b_occ[None]).sum()), mi * ki * ni,
-            int((a_full[:, :, None] & b_full[None]).sum()), a_full.size * b_full.shape[1]]
+    pairs = a_occ[:, :, None] & b_occ[None]                   # (mi, ki, ni)
+    busy = pairs.reshape(mi // rm, rm, ki // rk, rk, ni // rn, rn).any(axis=(1, 3, 5))
+    return [int(pairs.sum()), mi * ki * ni,
+            int((a_full[:, :, None] & b_full[None]).sum()), a_full.size * b_full.shape[1],
+            int((~busy).sum())]
 
 
 def _probe(x, w, g) -> list:
-    """The probe's 8 counts of one call: each direction's tile counts,
+    """The probe's 9 counts of one call: each direction's tile counts,
     then the block counts summed over the three."""
     fwd, dx, dw = _counts(x, w), _counts(g, w.T), _counts(x.T, g)
-    return fwd[:2] + dx[:2] + dw[:2] + [fwd[2] + dx[2] + dw[2], fwd[3] + dx[3] + dw[3]]
+    return fwd[:2] + dx[:2] + dw[:2] + [fwd[i] + dx[i] + dw[i] for i in (2, 3, 4)]
 
 
 @pytest.mark.parametrize("remat_policy", ["full", "stash"])
 def test_tile_counter_equals_eager_counts(monkeypatch, remat_policy):
     """Each call's forward (x @ w), dx (g @ w.T) and dw (x.T @ g) tile
-    and block counts, taken from its operands by a host callback in the
+    and block counts (idle blocks among them), taken from its operands by a host callback in the
     backward rule, sum to the step's in-jit counter exactly: the layers
     recomputed by ``jax.checkpoint`` or restored from the memstash
     compressed stash."""
@@ -100,9 +104,11 @@ def test_tile_counter_equals_eager_counts(monkeypatch, remat_policy):
     assert len(seen) == 4 * 2  # in_proj and out_proj of 4 scanned layers
     want = np.sum(seen, axis=0)
     assert tiles.dtype == np.float32 and tiles.tolist() == want.tolist()
-    assert len(want) == mm_bwd.PROBE_SIZE == 8
-    # the zeroed weight tile: skipped tiles, and blocks off the one-dot path
+    assert len(want) == mm_bwd.PROBE_SIZE == 9
+    # the zeroed weight tile: skipped tiles, blocks off the one-dot path,
+    # and idle blocks whose copies the kernel elides
     assert want[0] < want[1] and want[2] < want[3] and want[6] < want[7]
+    assert 0 < want[8] < want[7]
 
 
 def test_dense_step_counts_nothing():

@@ -2,7 +2,8 @@
 
 Each test lowers one registered ``pallas`` lowering at the real widths the
 train and serve paths use (``llama3.2-1b`` MLP matmuls, ``mamba2-780m``'s
-in_proj and out_proj matmuls and SSD scan, one slot of the llama KV pool) and compiles it for one chip of
+in_proj and out_proj matmuls and SSD scan, ``deepseek-v2-lite``'s routed
+expert matmuls, one slot of the llama KV pool) and compiles it for one chip of
 a ``v5e:2x2`` topology described without the chip.  The TPU compiler
 refuses here what it would refuse on the chip: tilings the (8, 128) rule
 forbids, casts and reductions Mosaic does not lower, VMEM overuse.
@@ -25,9 +26,12 @@ from jax.sharding import SingleDeviceSharding
 M, D, F = 2048, 2048, 8192
 # masked_matmul's (M, K, N): llama's MLP up projection; mamba2-780m's
 # in_proj (d_model 1536 -> 6448, which pads to 6528) and out_proj
-# (d_inner 3072 -> 1536), at batch 1 x 2048.
+# (d_inner 3072 -> 1536), at batch 1 x 2048; deepseek-v2-lite's routed
+# expert gate/up (d_model 2048 -> d_ff 1408) and down projections over
+# an 8192-row dropless buffer (batch 2 x 4096).
 MATMULS = {"llama_up": (M, D, F), "mamba_in_proj": (2048, 1536, 6448),
-           "mamba_out_proj": (2048, 3072, 1536)}
+           "mamba_out_proj": (2048, 3072, 1536),
+           "expert_up": (8192, 2048, 1408), "expert_down": (8192, 1408, 2048)}
 # mamba2-780m: 48 heads of 64, one state group of 128; batch 2 x 1024.
 SSD_B, SSD_S, SSD_H, SSD_P, SSD_G, SSD_N = 2, 1024, 48, 64, 1, 128
 # the llama K (or V) pool leaf the engine packs every decode tick:
