@@ -23,11 +23,11 @@ import jax.numpy as jnp
 
 from repro.kernels import registry
 from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.masked_matmul.backward import (
-    masked_matmul_dw,
-    masked_matmul_dx,
+from repro.kernels.masked_matmul.ops import (
+    grad_product,
+    masked_matmul,
+    tile_skip_fraction,
 )
-from repro.kernels.masked_matmul.ops import masked_matmul, tile_skip_fraction
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.stochastic_round.ops import stochastic_round
 
@@ -43,6 +43,13 @@ def _time(fn, *args, iters: int = 10, **kw) -> float:
 def _resolved(op: str) -> str:
     # planning lookup for row attribution; the timed call itself counts
     return registry.resolve(op, _count=False).name
+
+
+def _grad(op: str):
+    """The backward product of ``op`` (masked_matmul_dx / _dw) through
+    its resolved lowering, as the training step runs it."""
+    lowering = registry.resolve(op, _count=False)
+    return lambda a, b: grad_product(lowering, a, b)[0]
 
 
 def rows() -> list[tuple]:
@@ -70,11 +77,11 @@ def rows() -> list[tuple]:
     # derived = measured backward tile-skip fraction
     g = jnp.round(jax.random.normal(jax.random.fold_in(key, 8), (m, n)) * 64) / 256
     g = g.at[:256, :].set(0.0)
-    us = _time(masked_matmul_dx, g, w)
+    us = _time(_grad("masked_matmul_dx"), g, w)
     out.append(("kernel.masked_matmul_dx.512cube", us,
                 float(tile_skip_fraction(g, w.T)),
                 _resolved("masked_matmul_dx")))
-    us = _time(masked_matmul_dw, a, g)
+    us = _time(_grad("masked_matmul_dw"), a, g)
     out.append(("kernel.masked_matmul_dw.512cube", us,
                 float(tile_skip_fraction(a.T, g)),
                 _resolved("masked_matmul_dw")))

@@ -29,7 +29,7 @@ FLAGS = (
     flag("--mode", "numerics.mode",
          choices=["dense", "quant", "quant_sparse"]),
     flag("--backward-sparsity", "sparsity.backward",
-         choices=["none", "auto", "ref", "jnp", "interpret", "pallas"]),
+         choices=["none", "auto"]),
     flag("--ckpt-dir", "train.ckpt_dir"),
 )
 

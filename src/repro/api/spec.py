@@ -175,7 +175,7 @@ class NumericsSection:
 class SparsitySection:
     """Backward-direction sparsity (the forward mask is numerics.mode)."""
 
-    backward: str = "auto"  # none | auto | ref | jnp | interpret | pallas
+    backward: str = "auto"  # none | auto; a lowering is pinned in kernels.policy
     probe_density: float = 0.5  # dryrun sparsity/kv probe density
 
 
@@ -617,7 +617,7 @@ class RunSpec:
             self.numerics.stochastic, self.run != "serve")
         spring = dataclasses.replace(
             MODES[self.numerics.mode], stochastic=stochastic,
-            kernels=kernel_policy)
+            kernels=kernel_policy, backward_sparsity=self.sparsity.backward)
         if spring.is_quantized:
             spring = dataclasses.replace(
                 spring,
@@ -644,10 +644,8 @@ class RunSpec:
                        weight_format=SPRING_FORMAT
                        if self.numerics.fixed_point_weights else None))
             if self.run == "train":
-                step = StepConfig(
-                    spring=spring, backward_sparsity=self.sparsity.backward,
-                    memstash=memstash, optimizer=opt,
-                    microbatch=self.shape.microbatch)
+                step = StepConfig(spring=spring, memstash=memstash, optimizer=opt,
+                                  microbatch=self.shape.microbatch)
             else:  # dryrun
                 microbatch = self.shape.microbatch
                 if microbatch is None and SHAPES[self.shape.cell].kind == "train":
@@ -659,8 +657,7 @@ class RunSpec:
                 if self.shape.layout == "fsdp":
                     rules += FSDP_RULES
                 step = StepConfig(
-                    spring=spring, backward_sparsity=self.sparsity.backward,
-                    optimizer=opt, microbatch=microbatch,
+                    spring=spring, optimizer=opt, microbatch=microbatch,
                     rules_override=rules, int8_cache=self.serving.int8_cache)
 
         return ResolvedRun(

@@ -11,12 +11,10 @@ modes (``SpringMode``):
                  operands) and, on TPU, all-zero MXU tiles are skipped by
                  the ``masked_matmul`` Pallas kernel (paper P1).
 
-On CPU (this container, and the 512-host-device dry-run) the quant_sparse
-path lowers to the vectorized jnp equivalent — Pallas-for-TPU cannot lower
-on the CPU backend, and interpret-mode callbacks would poison
-``cost_analysis``.  Backend selection is the ``kernels`` KernelPolicy:
-each matmul resolves ``masked_matmul`` through ``repro.kernels.registry``
-(auto picks Pallas on TPU, the differentiable jnp lowering elsewhere).
+How a quant_sparse product is lowered and differentiated belongs to
+``kernels/masked_matmul`` alone: ``spring_matmul`` hands it the operands
+and ``SpringConfig.kernels``, under which it resolves its forward, dx and
+dw lowerings (auto picks Pallas on TPU, the ``ref`` lowering elsewhere).
 """
 
 from __future__ import annotations
@@ -34,14 +32,15 @@ from repro.core.fixedpoint import (
     ste_quantize_stochastic,
 )
 from repro.kernels.registry import KernelPolicy
+from repro.kernels.masked_matmul import ops as mm_ops
 
 SpringMode = Literal["dense", "quant", "quant_sparse"]
 
-#: Backward-sparsity switch values: "none" differentiates through the
-#: forward lowering (dense autodiff); "auto" routes dL/dX / dL/dW through
-#: the registry-resolved masked_matmul_dx/dw kernels; a concrete impl name
-#: pins the backward backend independently of the forward one.
-BACKWARD_SPARSITY_CHOICES = ("none", "auto", "ref", "jnp", "interpret", "pallas")
+#: Backward-sparsity switch values: "none" takes the plain float32 product
+#: and JAX autodiff; "auto" routes the product through masked_matmul's
+#: custom_vjp, whose dL/dX / dL/dW are the masked_matmul_dx/dw lowerings
+#: (pinned, like the forward, only through ``kernels``).
+BACKWARD_SPARSITY_CHOICES = ("none", "auto")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,13 +52,13 @@ class SpringConfig:
     # Deterministic rounding for activations on the fwd of *inference*;
     # training always uses SR (the paper's convergence argument).
     stochastic: bool = True
-    # Kernel-dispatch policy: per-op backend pins + global default,
-    # resolved through repro.kernels.registry at every kernel call site.
+    # Kernel-dispatch policy: per-op backend pins + global default, under
+    # which every kernel call site resolves its lowering.
     kernels: KernelPolicy = KernelPolicy()
-    # Sparsity-aware backward pass (quant_sparse mode only): dL/dX and
-    # dL/dW flow through the masked_matmul_dx/dw registry ops so tile
-    # skipping and binary-mask wire savings apply to training, not just
-    # the forward pass (DESIGN.md §8).  Forward numerics are unchanged.
+    # Sparsity-aware backward pass (quant_sparse mode only): "auto" sends
+    # dL/dX and dL/dW through the masked_matmul_dx/dw ops so tile
+    # skipping applies to training, not just the forward pass (DESIGN.md
+    # §8); "none" keeps the plain float32 product and autodiff.
     backward_sparsity: str = "auto"
     # Compute dtype of the dense baseline path.
     dense_dtype: jnp.dtype = jnp.bfloat16
@@ -167,37 +166,12 @@ def spring_matmul(
         w = w * w_mask.astype(w.dtype)
     wq = _q(w, cfg, keys, role="weight")
 
-    if cfg.is_sparse:
-        from repro.kernels import registry
-        from repro.kernels.masked_matmul import ops as mm_ops
-
-        # 2-D calls route the backward through the sparsity-aware dx/dw
-        # kernels; batched matmuls (rare: MoE dispatch paths) keep dense
-        # autodiff — the tiled kernels are 2-D by construction.
-        bwd = cfg.backward_sparsity if cfg.sparse_backward \
-            and xq.ndim == 2 and wq.ndim == 2 else "none"
-        kimpl = registry.resolve_with(cfg.kernels, "masked_matmul")
-        if kimpl.name in ("pallas", "interpret"):
-            # tile-skipping kernel: SR epilogue fused on the MAC lanes
-            # (the outer _q is then an on-grid identity); without the
-            # custom_vjp backward this path is forward-only (Pallas calls
-            # define no autodiff rule)
-            y = mm_ops.masked_matmul(xq, wq, impl=kimpl.name, backward=bwd,
-                                     probe=probe)
-        elif bwd != "none":
-            # "ref"/auto-CPU with sparse backward: the forward is the ref
-            # impl with the SR epilogue disabled — bit-identical to the
-            # dense jnp lowering below (ref(apply_sr=False) IS jnp.dot) —
-            # while dL/dX / dL/dW resolve through masked_matmul_dx/dw.
-            # The STE epilogue still comes from the outer _q.
-            y = mm_ops.masked_matmul(xq, wq, impl="ref", apply_sr=False,
-                                     backward=bwd, probe=probe)
-        else:
-            # "ref"/auto-CPU: the differentiable jnp lowering — fp32
-            # accumulate on the fixed-point grid (DESIGN.md deviation 2)
-            # with the SR epilogue applied below via the STE wrapper, so
-            # gradients flow during quant_sparse training.
-            y = jnp.matmul(xq.astype(jnp.float32), wq.astype(jnp.float32))
+    if cfg.sparse_backward and xq.ndim == 2 and wq.ndim == 2:
+        # masked_matmul's custom_vjp: tile skipping forward and backward,
+        # the SR rule of its lowering kept there; batched matmuls (rare:
+        # MoE dispatch paths) keep the plain product, as the tiled kernels
+        # are 2-D by construction
+        y = mm_ops.quant_sparse_matmul(xq, wq, cfg.kernels, probe)
     else:
         # fp32 accumulate on the fixed-point grid (DESIGN.md deviation 2).
         y = jnp.matmul(xq.astype(jnp.float32), wq.astype(jnp.float32))
@@ -208,8 +182,8 @@ def spring_matmul(
 
 # ---------------------------------------------------------------------------
 # Sparsity-aware conv backward: both backward GEMMs of an NHWC conv are
-# matmuls over patch matrices, so they route through the registry-resolved
-# masked_matmul_dx/dw kernels exactly like the fc layers (DESIGN.md §8):
+# matmuls over patch matrices, so they route through the masked_matmul_dx/dw
+# lowerings exactly like the fc layers (DESIGN.md §8):
 #
 #   dW = patches(x).T @ g      — the stashed ReLU-sparse activation re-read
 #   dX = patches~(g) @ rot(w)  — the ReLU-masked cotangent, stride-dilated
@@ -232,23 +206,19 @@ def _conv_nhwc(x, w, stride, padding):
 
 
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _conv_with_sparse_bwd(x, w, probe, stride, padding, bwd_impl):
+def _conv_with_sparse_bwd(x, w, probe, stride, padding, lowerings):
     del probe
     return _conv_nhwc(x, w, stride, padding)
 
 
-def _conv_sb_fwd(x, w, probe, stride, padding, bwd_impl):
+def _conv_sb_fwd(x, w, probe, stride, padding, lowerings):
     del probe
     return _conv_nhwc(x, w, stride, padding), (x, w)
 
 
-def _conv_sb_bwd(stride, padding, bwd_impl, res, g):
-    from repro.kernels.masked_matmul import ops as mm_ops
-    from repro.kernels.masked_matmul.backward import (
-        masked_matmul_dw, masked_matmul_dx)
-
+def _conv_sb_bwd(stride, padding, lowerings, res, g):
+    dx_lowering, dw_lowering = lowerings
     x, w = res
-    impl = None if bwd_impl == "auto" else bwd_impl
     n, h, wd, cin = x.shape
     r, s, _, cout = w.shape
     oh, ow = g.shape[1], g.shape[2]
@@ -259,7 +229,7 @@ def _conv_sb_bwd(stride, padding, bwd_impl, res, g):
     p = _lax.conv_general_dilated_patches(
         x, filter_shape=(r, s), window_strides=stride, padding=padding,
         dimension_numbers=_CONV_DNUMS).reshape(-1, cin * r * s)
-    dw = masked_matmul_dw(p, g2, impl=impl)
+    dw, dw_counts = mm_ops.grad_product(dw_lowering, p, g2)
     dw = dw.reshape(cin, r, s, cout).transpose(1, 2, 0, 3)
 
     # dX: transpose-conv as dilated cotangent patches x flipped weights.
@@ -274,12 +244,10 @@ def _conv_sb_bwd(stride, padding, bwd_impl, res, g):
         lhs_dilation=stride, dimension_numbers=_CONV_DNUMS)
     pg = pg.reshape(-1, cout * r * s)
     wt = w[::-1, ::-1].transpose(3, 0, 1, 2).reshape(cout * r * s, cin)
-    dx = masked_matmul_dx(pg, wt.T, impl=impl)
+    dx, dx_counts = mm_ops.grad_product(dx_lowering, pg, wt.T)
     # the tile counter (see spring_matmul): the forward is a plain conv,
     # so only the dx and dw calls count
-    dx_counts = mm_ops.call_counts(pg, wt)
-    counts = mm_ops.probe_counts(jnp.zeros_like(dx_counts), dx_counts,
-                                 mm_ops.call_counts(p.T, g2))
+    counts = mm_ops.probe_counts(jnp.zeros_like(dx_counts), dx_counts, dw_counts)
     return dx.reshape(n, h, wd, cin), dw, counts
 
 
@@ -316,12 +284,10 @@ def spring_conv2d(
         # convs keep dense autodiff — their patch matrices interleave
         # groups and defeat the tiled kernels.
         if probe is None:
-            from repro.kernels.masked_matmul.backward import PROBE_SIZE
-
-            probe = jnp.zeros((PROBE_SIZE,), jnp.float32)
+            probe = jnp.zeros((mm_ops.PROBE_SIZE,), jnp.float32)
         y = _conv_with_sparse_bwd(
             xq.astype(jnp.float32), wq.astype(jnp.float32), probe,
-            tuple(stride), padding, cfg.backward_sparsity)
+            tuple(stride), padding, mm_ops.backward_lowerings(cfg.kernels))
         return _q(y, cfg, keys)
     y = jax.lax.conv_general_dilated(
         xq.astype(jnp.float32),

@@ -157,22 +157,10 @@ def _mm_kernel(
             out_ref[...] = jnp.clip(rounded * jnp.float32(2.0**-fl), min_v, max_v)
 
 
-def masked_matmul_pallas(
-    x: jax.Array,
-    w: jax.Array,
-    x_occ: jax.Array,
-    w_occ: jax.Array,
-    x_full: jax.Array,
-    w_full: jax.Array,
-    fetch: jax.Array,
-    seed: jax.Array,
-    *,
-    il: int = 4,
-    fl: int = 16,
-    apply_sr: bool = True,
-    interpret: bool = False,
-) -> jax.Array:
-    """(M,K) @ (K,N) with tile skipping. Inputs must be TILE-padded.
+def masked_matmul_pallas(plan, seed: jax.Array, *, il: int = 4, fl: int = 16,
+                         apply_sr: bool = True, interpret: bool = False) -> jax.Array:
+    """(M,K) @ (K,N) with tile skipping, from the ``ops.MmPlan`` of the
+    product: its TILE-padded operands ``x`` and ``w``, and its tables.
 
     x_occ: (M/TILE, K/TILE) and w_occ: (K/TILE, N/TILE) int32 per tile;
     x_full: (M/bm, K/bk) and w_full: (K/bk, N/bn) int32 per block of
@@ -183,6 +171,8 @@ def masked_matmul_pallas(
     copies nothing).  The five tables and the seed are scalar-prefetched
     into SMEM (flattened row-major).
     """
+    x, w, x_occ, w_occ, x_full, w_full, fetch = (
+        plan.x, plan.w, plan.x_occ, plan.w_occ, plan.x_full, plan.w_full, plan.fetch)
     m, k = x.shape
     k2, n = w.shape
     assert k == k2 and m % TILE == 0 and n % TILE == 0 and k % TILE == 0

@@ -333,7 +333,10 @@ def _metrics_registry():
     return default_registry()
 
 
-def _record_dispatch(op: str, name: str) -> None:
+def record_dispatch(op: str, name: str) -> None:
+    """Count one dispatch of ``op`` to impl ``name``: :func:`resolve` does
+    unless ``_count=False``; a lowering resolved ahead of its run counts
+    where it runs."""
     _metrics_registry().inc(
         DISPATCH_METRIC, op=op, impl=name,
         help="kernel-registry resolutions per (op, impl)")
@@ -457,8 +460,8 @@ def resolve(op: str, impl: Optional[str] = None, *, _count: bool = True,
     ``ValueError`` naming the impl and the ops that could.
 
     ``_count=False`` marks a *planning* resolution (config threading,
-    resolution tables): it is excluded from ``dispatch_counts()`` so only
-    the public-wrapper resolution that actually invokes the impl counts.
+    resolution tables, a lowering resolved ahead of its run): it is
+    excluded from ``dispatch_counts()`` so only the invocation counts.
     """
     ensure_registered()
     if op not in _OPS:
@@ -501,22 +504,28 @@ def resolve(op: str, impl: Optional[str] = None, *, _count: bool = True,
                     f"{caps}; supported by: {ok or 'none'}")
             kimpl = _auto_pick(op, **caps)
     if _count:
-        _record_dispatch(op, kimpl.name)
+        record_dispatch(op, kimpl.name)
     return kimpl
 
 
-def resolve_with(policy: Optional[KernelPolicy], op: str, **caps: Any) -> KernelImpl:
-    """Resolve ``op`` under a config-threaded policy (SpringConfig.kernels).
-
-    An ``auto`` policy defers to the ambient policy (context manager /
-    env var); a concrete one scopes itself for this resolution so its
-    global default keeps soft-fallback semantics.  This is a *planning*
-    resolution (the chosen impl name is then passed to the public
-    wrapper, which resolves again), so it does not count as a dispatch.
-    """
+def policy_scope(policy: Optional[KernelPolicy]):
+    """Scope a config-threaded policy (SpringConfig.kernels): an ``auto``
+    policy defers to the ambient one (context manager / env var); a
+    concrete one is pushed, so its global default keeps soft-fallback
+    semantics."""
     if policy is None or policy.is_auto:
-        return resolve(op, _count=False, **caps)
-    with kernel_policy(policy):
+        return contextlib.nullcontext()
+    return kernel_policy(policy)
+
+
+def resolve_with(policy: Optional[KernelPolicy], op: str, **caps: Any) -> KernelImpl:
+    """Resolve ``op`` under a config-threaded policy (:func:`policy_scope`).
+
+    This is a *planning* resolution (the chosen impl name is then passed
+    to the public wrapper, which resolves again), so it does not count as
+    a dispatch.
+    """
+    with policy_scope(policy):
         return resolve(op, _count=False, **caps)
 
 
@@ -530,10 +539,8 @@ def resolution_table(policy: Optional[KernelPolicy] = None,
     table reflects the ambient env/context policy the calls actually saw.
     """
     ensure_registered()
-    ctx = (kernel_policy(policy) if policy is not None and not policy.is_auto
-           else contextlib.nullcontext())
     out = {}
-    with ctx:
+    with policy_scope(policy):
         for op in sorted(_OPS):
             try:
                 out[op] = resolve(op, _count=False, **caps_by_op.get(op, {})).name

@@ -65,7 +65,7 @@ LEGACY_FLAGS = (
     flag("--variant", "dryrun.variant"),
     flag("--kernel-impl", "kernels.policy"),
     flag("--backward-sparsity", "sparsity.backward",
-         choices=["none", "auto", "ref", "jnp", "interpret", "pallas"]),
+         choices=["none", "auto"]),
     flag("--probe-density", "sparsity.probe_density", type=float),
 )
 

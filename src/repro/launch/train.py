@@ -43,7 +43,7 @@ LEGACY_FLAGS = (
     flag("--fixed-point-weights", "numerics.fixed_point_weights", const=True),
     flag("--kernel-impl", "kernels.policy"),
     flag("--backward-sparsity", "sparsity.backward",
-         choices=["none", "auto", "ref", "jnp", "interpret", "pallas"]),
+         choices=["none", "auto"]),
     flag("--stash", "memstash.policy", choices=["none", "remat", "stash"]),
     flag("--ckpt-dir", "train.ckpt_dir"),
     flag("--ckpt-every", "train.ckpt_every", type=int),
@@ -61,7 +61,7 @@ def train_loop(
     lr: float = 3e-3,
     fixed_point_weights: bool = False,
     kernel_impl: str | None = None,  # KernelPolicy spec, e.g. "ref" | "ssd_scan=jnp"
-    backward_sparsity: str = "auto",  # none | auto | ref | jnp | interpret | pallas
+    backward_sparsity: str = "auto",  # none | auto
     stash: str = "none",  # memstash policy: none | remat | stash
     ckpt_dir: str | None = None,
     ckpt_every: int = 100,

@@ -35,7 +35,7 @@ class SpringContext:
     memstash: Optional[MemstashConfig] = None
     # masked_matmul tile counter of a training step: a zero float32 vector
     # the loss is differentiated against; its gradient sums every call's
-    # issued/total grid steps (kernels/masked_matmul/backward.py).  Code
+    # issued/total grid steps (kernels/masked_matmul/ops.py).  Code
     # that hands the context to a custom_vjp passes it as an input.
     tile_probe: Optional[jax.Array] = None
 
@@ -55,16 +55,6 @@ class SpringContext:
         from repro.kernels import registry
 
         return registry.resolve_with(self.cfg.kernels, op, **caps).name
-
-    def backward_sparsity(self) -> str:
-        """The backward-sparsity switch in force for this context.
-
-        "none" unless the sparsity-aware custom_vjp backward is actually
-        in force (same ``sparse_backward`` gate the spring ops dispatch
-        on); otherwise the SpringConfig switch — "auto" or a pinned
-        backward impl name.
-        """
-        return self.cfg.backward_sparsity if self.cfg.sparse_backward else "none"
 
     def kernel_pinned(self, op: str) -> Optional[str]:
         """Non-auto impl explicitly pinned for ``op``, else None.

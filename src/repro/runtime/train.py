@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.spring_ops import DENSE, KeyGen, SpringConfig
-from repro.kernels.masked_matmul.backward import PROBE_SIZE
+from repro.kernels.masked_matmul.ops import PROBE_SIZE
 from repro.memstash.config import MemstashConfig
 from repro.models import encdec as ed_mod
 from repro.models import lm as lm_mod
@@ -38,12 +38,6 @@ from repro.runtime.sharding import DEFAULT_RULES, sharding_context
 @dataclasses.dataclass(frozen=True)
 class StepConfig:
     spring: SpringConfig = DENSE
-    # Sparsity-aware backward pass override: None inherits the
-    # SpringConfig.backward_sparsity field (default "auto" — dx/dW through
-    # the registry-resolved masked_matmul_dx/dw kernels in quant_sparse
-    # mode); launch CLIs set it explicitly ("none" | "auto" | impl name)
-    # so --backward-sparsity switches it without rebuilding SpringConfig.
-    backward_sparsity: Optional[str] = None
     prune_ratio: float = 0.0
     optimizer: OptimizerConfig = OptimizerConfig()
     # int8+error-feedback gradient reduction across the 'pod' mesh axis
@@ -119,16 +113,6 @@ def _loss_for(arch, cfg, params, batch, ctx):
     return lm_mod.lm_loss(params, cfg, batch["tokens"], ctx, batch.get("img_embeds"))
 
 
-def _spring_for(step_cfg: StepConfig) -> SpringConfig:
-    """SpringConfig with the step-level backward_sparsity override applied
-    (None = inherit whatever the SpringConfig itself says)."""
-    if step_cfg.backward_sparsity is None \
-            or step_cfg.spring.backward_sparsity == step_cfg.backward_sparsity:
-        return step_cfg.spring
-    return dataclasses.replace(step_cfg.spring,
-                               backward_sparsity=step_cfg.backward_sparsity)
-
-
 def _rules_for(step_cfg: StepConfig):
     if not step_cfg.rules_override:
         return None
@@ -159,7 +143,7 @@ def make_train_step(arch, step_cfg: StepConfig, mesh=None, reduced: bool = False
     ``jax.named_scope("spring_optimizer")``."""
     cfg = arch.reduced() if reduced else arch.config
     _, opt_update = make_optimizer(step_cfg.optimizer)
-    spring_cfg = _spring_for(step_cfg)
+    spring_cfg = step_cfg.spring
 
     # masked_matmul's tile counter: where the sparsity-aware backward is in
     # force, the loss is differentiated against a zero probe as well, whose

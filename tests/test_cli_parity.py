@@ -33,8 +33,8 @@ PARITY_CASES = [
     ("train", ["--stash", "stash"], ["--set", "memstash.policy=stash"]),
     ("train", ["--kernel-impl", "ref,ssd_scan=jnp"],
      ["--set", "kernels.policy=ref,ssd_scan=jnp"]),
-    ("train", ["--backward-sparsity", "jnp"],
-     ["--set", "sparsity.backward=jnp"]),
+    ("train", ["--backward-sparsity", "none"],
+     ["--set", "sparsity.backward=none"]),
     ("train", ["--arch", "qwen2-7b", "--reduced", "--steps", "7",
                "--batch", "2", "--seq", "16", "--mode", "quant",
                "--lr", "0.01", "--fixed-point-weights",
@@ -57,14 +57,14 @@ PARITY_CASES = [
       "--set", "shape.batch=2"]),
     ("dryrun", ["--arch", "qwen2-7b", "--shape", "train_4k",
                 "--mesh", "multi", "--mode", "quant_sparse",
-                "--backward-sparsity", "ref", "--kernel-impl", "ref",
+                "--backward-sparsity", "none", "--kernel-impl", "ref",
                 "--layout", "fsdp", "--seq-parallel", "--cache-int8",
                 "--quant-opt", "--variant", "v1", "--microbatch", "4",
                 "--probe-density", "0.25", "--no-unrolled-cost",
                 "--bf16-logits", "--remat-policy", "block_io"],
      ["--set", "arch.id=qwen2-7b", "--set", "shape.cell=train_4k",
       "--set", "shape.mesh=multi", "--set", "numerics.mode=quant_sparse",
-      "--set", "sparsity.backward=ref", "--set", "kernels.policy=ref",
+      "--set", "sparsity.backward=none", "--set", "kernels.policy=ref",
       "--set", "shape.layout=fsdp", "--set", "shape.seq_parallel=true",
       "--set", "serving.int8_cache=true", "--set", "dryrun.quant_opt=true",
       "--set", "dryrun.variant=v1", "--set", "shape.microbatch=4",

@@ -1,5 +1,5 @@
 """Smoke tests: each runnable example imports and completes ``main(steps=1)``
-under the default StepConfig (backward_sparsity="auto") — the examples are
+under the default backward_sparsity="auto" — the examples are
 documentation, so they must stay green (ISSUE 3, satellite 4)."""
 
 import importlib.util

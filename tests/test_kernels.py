@@ -122,18 +122,19 @@ def _fetch_cases():
 
 @pytest.mark.parametrize("case", list(_fetch_cases()))
 def test_masked_matmul_fetch_table(case):
-    """``prepare``'s fetch table against a loop over the grid: a working
+    """``prepare``'s work and fetch tables against a loop over the grid: a working
     step loads its own blocks, an idle step the previous step's (so the
     pipeline copies nothing for it), fully occupied operands give the
     identity; ``block_counts``' idle entry counts the idle steps."""
     x, w = _fetch_cases()[case]
     work, want = _grid_fetch(x, w)
-    tables = prepare(x, w)
-    nj, kk = tables[5].shape[1], tables[4].shape[1]
+    plan = prepare(x, w)
+    nj, kk = plan.w_full.shape[1], plan.x_full.shape[1]
     jb, kb = fetch_bits(nj, kk)
     # unpack (I', J', K') into the flat row-major step
     fetch = [((f >> (jb + kb)) * nj + ((f >> kb) & ((1 << jb) - 1))) * kk
-             + (f & ((1 << kb) - 1)) for f in np.asarray(tables[6]).tolist()]
+             + (f & ((1 << kb) - 1)) for f in np.asarray(plan.fetch).tolist()]
+    assert np.asarray(plan.work).reshape(-1).tolist() == work
     assert fetch == want
     for s, works in enumerate(work):
         if works:
@@ -141,7 +142,7 @@ def test_masked_matmul_fetch_table(case):
         elif s > 0:
             assert fetch[s] == fetch[s - 1]
     idle = work.count(False)
-    assert np.asarray(block_counts(x, w))[1:].tolist() == [len(work), idle]
+    assert np.asarray(block_counts(plan))[1:].tolist() == [len(work), idle]
     if case == "full":
         assert idle == 0 and fetch == list(range(len(work)))
     if case == "all_empty":

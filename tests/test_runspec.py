@@ -152,11 +152,11 @@ def test_resolver_matches_legacy_train_stepconfig():
 
     spec = train_spec("llama3.2-1b", mode="quant", lr=1e-2,
                       fixed_point_weights=True, kernel_impl="ref",
-                      backward_sparsity="jnp", stash="stash")
+                      backward_sparsity="none", stash="stash")
     r = spec.resolve()
     assert r.spring == dataclasses.replace(
-        MODES["quant"], kernels=KernelPolicy.parse("ref"))
-    assert r.step.backward_sparsity == "jnp"
+        MODES["quant"], kernels=KernelPolicy.parse("ref"), backward_sparsity="none")
+    assert r.step.spring == r.spring
     assert r.step.memstash == MemstashConfig(policy="stash")
     assert r.step.optimizer == OptimizerConfig(
         kind="adamw", lr=1e-2, warmup_steps=10, weight_format=SPRING_FORMAT)
@@ -283,7 +283,7 @@ def test_stepconfig_from_runspec_accepts_spec_dict_and_json():
 
     spec = build_spec("train", use_env=False,
                       sets=["numerics.mode=quant_sparse",
-                            "sparsity.backward=jnp"])
+                            "sparsity.backward=none"])
     want = spec.resolve().step
     assert StepConfig.from_runspec(spec) == want
     assert StepConfig.from_runspec(spec.to_dict()) == want

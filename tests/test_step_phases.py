@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.masked_matmul import backward as mm_bwd
+from repro.kernels.masked_matmul import ops as mm_ops
 
 SCOPES = ("spring_quantize", "spring_mm_prep", "spring_ssd_scan_vjp", "spring_optimizer")
 
@@ -88,14 +88,14 @@ def test_tile_counter_equals_eager_counts(monkeypatch, remat_policy):
     recomputed by ``jax.checkpoint`` or restored from the memstash
     compressed stash."""
     seen = []
-    real = mm_bwd._mm_bw.bwd
+    real = mm_ops._mm.bwd
 
-    def bwd(il, fl, apply_sr, fwd_impl, bwd_impl, res, g):
+    def bwd(il, fl, apply_sr, lowerings, res, g):
         x, w = res[0], res[1]
         jax.debug.callback(lambda x, w, g: seen.append(_probe(x, w, g)), x, w, g)
-        return real(il, fl, apply_sr, fwd_impl, bwd_impl, res, g)
+        return real(il, fl, apply_sr, lowerings, res, g)
 
-    monkeypatch.setattr(mm_bwd._mm_bw, "bwd", bwd)
+    monkeypatch.setattr(mm_ops._mm, "bwd", bwd)
     sets = ["memstash.policy=stash"] if remat_policy == "stash" else []
     step, state, batch = _step(remat_policy=remat_policy, sets=sets)
     _, metrics = step(state, batch)
@@ -104,7 +104,7 @@ def test_tile_counter_equals_eager_counts(monkeypatch, remat_policy):
     assert len(seen) == 4 * 2  # in_proj and out_proj of 4 scanned layers
     want = np.sum(seen, axis=0)
     assert tiles.dtype == np.float32 and tiles.tolist() == want.tolist()
-    assert len(want) == mm_bwd.PROBE_SIZE == 9
+    assert len(want) == mm_ops.PROBE_SIZE == 9
     # the zeroed weight tile: skipped tiles, blocks off the one-dot path,
     # and idle blocks whose copies the kernel elides
     assert want[0] < want[1] and want[2] < want[3] and want[6] < want[7]
@@ -115,6 +115,15 @@ def test_dense_step_counts_nothing():
     step, state, batch = _step(mode="dense")
     _, metrics = step(state, batch)
     assert "mm_tiles" not in metrics
+
+
+def test_backward_none_step_under_kernel_lowering():
+    """``sparsity.backward=none`` trains with the kernel lowering pinned:
+    the plain float32 product and autodiff, nothing counted."""
+    step, state, batch = _step(sets=["sparsity.backward=none",
+                                     "kernels.policy=masked_matmul=interpret"])
+    _, metrics = step(state, batch)
+    assert np.isfinite(float(metrics["loss"])) and "mm_tiles" not in metrics
 
 
 def test_step_names_its_device_phases():
